@@ -325,7 +325,8 @@ def run_sharded(quick: bool = True, n_in: int = 1024, n_out: int = 4096,
     if n_dev < 2:
         emit("kernel/sharded_skipped", 0.0, {"devices": n_dev})
         return
-    mesh = jax.make_mesh((n_dev,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((n_dev,), ("model",))
     x = jax.random.normal(jax.random.key(0), (m, n_in))
     densities = (0.25,) if quick else (0.5, 0.25, 0.125)
     for rho in densities:

@@ -59,10 +59,12 @@ class KernelCase:
 
 
 def _spec_block_shape(spec) -> Optional[Tuple[int, ...]]:
+    """Block shape as ints; a squeezed dim (``pl.squeezed``) is a
+    one-element block of that dim."""
     bs = getattr(spec, "block_shape", None)
     if bs is None:
         return None
-    return tuple(int(b) for b in bs)
+    return tuple(b if isinstance(b, int) else 1 for b in bs)
 
 
 def analyze_launch(launch: CapturedLaunch, case: KernelCase,
@@ -309,7 +311,7 @@ def _paged_decode_case() -> KernelCase:
             jnp.asarray(lengths), window=None, softcap=None, scale=1.0,
             interpret=True, name="paged_decode_attention")
     # the online-softmax finalize fires on the last page of each row
-    return KernelCase("paged_decode_attention", build, epilogue_axis=2)
+    return KernelCase("paged_decode_attention", build, epilogue_axis=1)
 
 
 def kernel_cases() -> List[KernelCase]:

@@ -101,12 +101,14 @@ def _collective_axes(jaxpr) -> Set[str]:
     return axes
 
 
-def _names_axes(names) -> Set[str]:
-    """Flatten a shard_map in_names/out_names entry ({dim: (axes,)}) to the
-    set of mesh axes it maps."""
+def _spec_axes(spec) -> Set[str]:
+    """Flatten one shard_map in_specs/out_specs entry (a PartitionSpec
+    whose dims name a mesh axis, a tuple of axes, or None) to the set of
+    mesh axes it maps."""
     out: Set[str] = set()
-    for axes in dict(names).values():
-        out.update(axes if isinstance(axes, (list, tuple)) else (axes,))
+    for axes in spec:
+        if axes is not None:
+            out.update(axes if isinstance(axes, (list, tuple)) else (axes,))
     return out
 
 
@@ -217,16 +219,14 @@ def _lint_shard_map(eqn, subject: str) -> List[Finding]:
         body = body.jaxpr
     if body is None:
         return f
-    in_names = params.get("in_names") or ()
-    out_names = params.get("out_names") or ()
     mapped_in: Set[str] = set()
-    for names in in_names:
-        mapped_in |= _names_axes(names)
+    for spec in params["in_specs"]:
+        mapped_in |= _spec_axes(spec)
     if not mapped_in:
         return f  # fully replicated body: no reduction obligation
     have = _collective_axes(body)
-    for o, names in enumerate(out_names):
-        missing = mapped_in - _names_axes(names) - have
+    for o, spec in enumerate(params["out_specs"]):
+        missing = mapped_in - _spec_axes(spec) - have
         for ax in sorted(missing):
             f.append(Finding(
                 "SL205", subject,
@@ -527,7 +527,8 @@ def run(config_names: Optional[Sequence[str]] = None,
     mesh = None
     errors: List[str] = []
     if n_dev >= need:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        from ..launch.mesh import make_mesh
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         errors.append(
             f"sharded-path lint skipped: {n_dev} device(s) < {need} "

@@ -38,8 +38,8 @@ right block, fan-in slot) is unchanged, so the per-expert schedule, VMEM
 residency, and clash-freedom argument are identical to the unbatched case.
 
 All kernels are validated against ``ref.py`` in interpret mode (CPU) by
-``tests/test_kernels.py``; on real TPUs the same code path compiles to
-Mosaic.
+``tests/test_kernels.py``; ``tests/test_tpu_compile.py`` compiles them to
+Mosaic for a described TPU v5e at qwen2_7b's widths.
 """
 from __future__ import annotations
 
@@ -93,7 +93,8 @@ def mask_cotangent(dy: jax.Array, aux: jax.Array,
     if activation is None:
         return dy
     if activation == "relu":
-        return dy * (aux > 0).astype(dy.dtype)
+        # compare in f32: the v5e vector unit has no bf16 comparison
+        return dy * (aux.astype(jnp.float32) > 0).astype(dy.dtype)
     if activation == "gelu":
         # analytic derivative of the tanh approximation — matches what
         # jax.vjp derives for jax.nn.gelu(approximate=True) to rounding
@@ -105,6 +106,14 @@ def mask_cotangent(dy: jax.Array, aux: jax.Array,
             + 0.5 * z * (1.0 - t * t) * c * (1.0 + 3.0 * a * z * z)
         return (dy.astype(jnp.float32) * g).astype(dy.dtype)
     raise ValueError(f"unsupported fused activation {activation!r}")
+
+
+def _bias_tile(br: int) -> tuple:
+    """Block of one right block's bias (or db) row in the ``(n_rb, 1, bR)``
+    layout: the block-row dim is squeezed and the trailing ``(1, bR)``
+    equals the array's own trailing dims, which is what the TPU's (8, 128)
+    block rule accepts for a one-row tile."""
+    return (pl.squeezed, 1, br)
 
 
 def _fwd_kernel(idx_ref, *refs, d_in_b: int, activation: Optional[str],
@@ -171,7 +180,7 @@ def _fwd_kernel_batched(idx_ref, *refs, d_in_b: int,
         def _epilogue():
             z = y_ref[0]
             if has_bias:
-                z = z + b_ref[0].astype(z.dtype)  # (1, bR) broadcasts
+                z = z + b_ref[...].astype(z.dtype)  # (1, bR) broadcasts
             if save_preact:
                 out_refs[1][0] = z
             y_ref[0] = apply_activation(z, activation)
@@ -246,7 +255,7 @@ def _fwd_kernel_quant_batched(idx_ref, scale_ref, *refs, d_in_b: int,
         def _epilogue():
             z = y_ref[0]
             if has_bias:
-                z = z + b_ref[0].astype(z.dtype)
+                z = z + b_ref[...].astype(z.dtype)
             y_ref[0] = apply_activation(z, activation)
 
 
@@ -274,9 +283,9 @@ def _csd_spmm_fwd_quant(x, w, w_scale, block_idx, *, bias, activation,
     operands = [jnp.asarray(block_idx, jnp.int32),
                 jnp.asarray(w_scale, jnp.float32), x, w]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, br),
-                                     lambda i, r, f, idx, sc: (r, 0)))
-        operands.append(bias.reshape(n_rb, br))
+        in_specs.append(pl.BlockSpec(_bias_tile(br),
+                                     lambda i, r, f, idx, sc: (r, 0, 0)))
+        operands.append(bias.reshape(n_rb, 1, br))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -316,9 +325,9 @@ def _csd_spmm_fwd_quant_batched(x, w, w_scale, block_idx, *, bias,
     operands = [jnp.asarray(block_idx, jnp.int32),
                 jnp.asarray(w_scale, jnp.float32), x, w]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, br),
-                                     lambda e, i, r, f, idx, sc: (e, r, 0)))
-        operands.append(bias.reshape(e, n_rb, br))
+        in_specs.append(pl.BlockSpec((pl.squeezed,) + _bias_tile(br),
+                                     lambda e, i, r, f, idx, sc: (e, r, 0, 0)))
+        operands.append(bias.reshape(e, n_rb, 1, br))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -359,9 +368,9 @@ def _csd_spmm_fwd_batched(x, w, block_idx, *, bias, activation, save_preact,
     ]
     operands = [jnp.asarray(block_idx, jnp.int32), x, w]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, br),
-                                     lambda e, i, r, f, idx: (e, r, 0)))
-        operands.append(bias.reshape(e, n_rb, br))
+        in_specs.append(pl.BlockSpec((pl.squeezed,) + _bias_tile(br),
+                                     lambda e, i, r, f, idx: (e, r, 0, 0)))
+        operands.append(bias.reshape(e, n_rb, 1, br))
     out_spec = pl.BlockSpec((1, block_m, br),
                             lambda e, i, r, f, idx: (e, i, r))
     out_shape = jax.ShapeDtypeStruct((e, m, n_rb * br), acc_dtype)
@@ -457,10 +466,10 @@ def csd_spmm_fwd(
     ]
     operands = [jnp.asarray(block_idx, jnp.int32), x, w]
     if has_bias:
-        # bias as (n_rb, bR): one right-block slice per output tile.
-        in_specs.append(pl.BlockSpec((1, br),
-                                     lambda i, r, f, idx: (r, 0)))
-        operands.append(bias.reshape(n_rb, br))
+        # bias as (n_rb, 1, bR): one right-block row per output tile.
+        in_specs.append(pl.BlockSpec(_bias_tile(br),
+                                     lambda i, r, f, idx: (r, 0, 0)))
+        operands.append(bias.reshape(n_rb, 1, br))
     out_spec = pl.BlockSpec((block_m, br), lambda i, r, f, idx: (i, r))
     out_shape = jax.ShapeDtypeStruct((m, n_rb * br), acc_dtype)
     if save_preact:
@@ -722,7 +731,7 @@ def csd_spmm_dw(
     x_map = imap(lambda e_, r, f, i, idx: e_ + (i, idx[r, f]))
     dy_map = imap(lambda e_, r, f, i, idx: e_ + (i, r))
     dw_map = imap(lambda e_, r, f, i, idx: e_ + (r, f, 0, 0))
-    db_map = imap(lambda e_, r, f, i, idx: e_ + (r, 0))
+    db_map = imap(lambda e_, r, f, i, idx: e_ + (r, 0, 0))
 
     in_specs = [pl.BlockSpec(one + (block_m, bl), x_map),
                 pl.BlockSpec(one + (block_m, br), dy_map)]
@@ -738,9 +747,10 @@ def csd_spmm_dw(
     dw_shape = jax.ShapeDtypeStruct(
         ((e,) if batched else ()) + (n_rb, d_in_b, bl, br), jnp.float32)
     if want_db:
-        out_specs = (dw_spec, pl.BlockSpec(one + (1, br), db_map))
+        sq = (pl.squeezed,) if batched else ()
+        out_specs = (dw_spec, pl.BlockSpec(sq + _bias_tile(br), db_map))
         out_shapes = (dw_shape, jax.ShapeDtypeStruct(
-            ((e,) if batched else ()) + (n_rb, br), jnp.float32))
+            ((e,) if batched else ()) + (n_rb, 1, br), jnp.float32))
     else:
         out_specs = dw_spec
         out_shapes = dw_shape

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics as obs_metrics
+
 _NEG_INF = -1e30
 _LANES = 128
 
@@ -152,17 +154,24 @@ def flash_attention(
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                          scale: float, window: Optional[int],
                          softcap: Optional[float], page_size: int,
-                         n_pages: int, quant: bool = False):
-    """``quant`` selects int8 KV pages: two extra per-token scale refs
-    ((1, page_size) tiles of the scale buffers, selected by the same
-    page-table index map) dequantize K/V in register — the pages stream
-    from HBM at 1 byte/element."""
+                         n_pages: int, n_kv_heads: int, quant: bool = False):
+    """One grid step = one page of one sequence, all KV heads at once: the
+    page's ``(page, Hkv, Dh)`` block keeps the pool's full trailing dims
+    (the TPU's block rule refuses a single-head slice of them), and each
+    head's online-softmax state lives in its own row of the scratch.
+
+    ``quant`` selects int8 KV pages: two extra per-token scale refs
+    (``(1, page_size)`` rows of the scale buffers, selected by the same
+    page-table index map) dequantize in register — the key scale multiplies
+    the logits and the value scale the probabilities, so both stay in the
+    lane orientation the logits already have. Pages stream from HBM at
+    1 byte/element."""
     if quant:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         (o_ref, acc_ref, m_ref, l_ref), ks_ref, vs_ref = rest, None, None
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -170,89 +179,93 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale    # (G, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (page, Dh)
-    if quant:
-        k = k * ks_ref[0][:, None]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, page)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
     length = len_ref[b]                            # valid keys: kpos < length
+    groups = q_ref.shape[2]
     kpos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
+        jnp.int32, (groups, page_size), 1)
     mask = kpos < length
     if window is not None:
         mask &= kpos > (length - 1) - window
-    s = jnp.where(mask, s, _NEG_INF)
 
-    m_prev = m_ref[:, :1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    pexp = jnp.exp(s - m_new)
-    pexp = jnp.where(m_new > _NEG_INF / 2, pexp, 0.0)
-    corr = jnp.where(m_prev > _NEG_INF / 2, jnp.exp(m_prev - m_new), 0.0)
-    l_new = corr * l_ref[:, :1] + jnp.sum(pexp, axis=1, keepdims=True)
+    for h in range(n_kv_heads):
+        q = q_ref[0, h].astype(jnp.float32) * scale    # (G, Dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)      # (page, Dh)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if quant:
+            s = s * ks_ref[...]                        # (1, page)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(mask, s, _NEG_INF)
 
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (page, Dh)
-    if quant:
-        v = v * vs_ref[0][:, None]
-    pv = jax.lax.dot_general(pexp, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_prev = m_ref[h][:, :1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        pexp = jnp.exp(s - m_new)
+        pexp = jnp.where(m_new > _NEG_INF / 2, pexp, 0.0)
+        corr = jnp.where(m_prev > _NEG_INF / 2, jnp.exp(m_prev - m_new),
+                         0.0)
+        l_new = corr * l_ref[h][:, :1] + jnp.sum(pexp, axis=1,
+                                                 keepdims=True)
+        if quant:
+            pexp = pexp * vs_ref[...]                  # (1, page)
+        v = v_ref[0, :, h, :].astype(jnp.float32)      # (page, Dh)
+        pv = jax.lax.dot_general(pexp, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[h] = acc_ref[h] * corr + pv
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(p == n_pages - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
                          window, softcap, scale, interpret,
                          k_scale=None, v_scale=None):
     b, hkv, g, dh = q.shape
-    page_size = k_pages.shape[1]
+    pool, page_size = k_pages.shape[:2]
     n_pages = page_table.shape[1]
     quant = k_scale is not None
-    # (P, page, Hkv, Dh) blocked as (1 page-row, page, 1 head, Dh); the
-    # physical page id comes from the scalar-prefetched table — this is
-    # the kernel-side form of the free-list indirection
+
+    # the physical page id comes from the scalar-prefetched table — this
+    # is the kernel-side form of the free-list indirection
+    def page(bb, p, pt, ln):
+        return jnp.maximum(pt[bb, p], 0)
+
     kv_spec = pl.BlockSpec(
-        (1, page_size, 1, dh),
-        lambda bb, h, p, pt, ln: (jnp.maximum(pt[bb, p], 0), 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, dh),
-                     lambda bb, h, p, pt, ln: (bb, h, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+        (1, page_size, hkv, dh),
+        lambda bb, p, pt, ln: (page(bb, p, pt, ln), 0, 0, 0))
+    q_spec = pl.BlockSpec((1, hkv, g, dh),
+                          lambda bb, p, pt, ln: (bb, 0, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [page_table, lengths, q, k_pages, v_pages]
     if quant:
-        # per-token scale tile of the (P+1, page) buffers, same page id
+        # per-token scale row of the (P+1, page) buffers, same page id;
+        # viewed as (P+1, 1, page) so the one-row tile spans full dims
         sc_spec = pl.BlockSpec(
-            (1, page_size),
-            lambda bb, h, p, pt, ln: (jnp.maximum(pt[bb, p], 0), 0))
+            (pl.squeezed, 1, page_size),
+            lambda bb, p, pt, ln: (page(bb, p, pt, ln), 0, 0))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [k_scale.reshape(pool, 1, page_size),
+                     v_scale.reshape(pool, 1, page_size)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_pages),
+        grid=(b, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bb, h, p, pt, ln: (bb, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),
-            pltpu.VMEM((g, _LANES), jnp.float32),
-            pltpu.VMEM((g, _LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, dh), jnp.float32),
+            pltpu.VMEM((hkv, g, _LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, _LANES), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window, softcap=softcap,
-        page_size=page_size, n_pages=n_pages, quant=quant)
+        page_size=page_size, n_pages=n_pages, n_kv_heads=hkv, quant=quant)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -310,6 +323,7 @@ def paged_decode_attention(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # (P, page_size) f32
     v_scale: Optional[jax.Array] = None,
+    mesh=None,
 ) -> jax.Array:
     """Single-token attention over a paged KV cache; returns like ``q``.
 
@@ -322,8 +336,11 @@ def paged_decode_attention(
     ``k_scale``/``v_scale`` select int8 KV pages (per-token scales from
     ``serving.kv_cache.write_kv_quant``): pages stream at 1 byte/element
     and are dequantized in register / post-gather.
+
+    Under a multi-device ``mesh`` the Pallas kernel runs whole on every
+    device (``ops.run_replicated``): XLA cannot partition it.
     """
-    from .ops import _resolve
+    from .ops import _resolve, run_replicated
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     lengths = lengths.astype(jnp.int32)
     page_table = page_table.astype(jnp.int32)
@@ -345,11 +362,23 @@ def paged_decode_attention(
             be = str(ent["backend"])
     if be is None:
         be = _resolve(backend)
+    # trace-time count, like the junctions' repro_junction_dispatch_total
+    obs_metrics.get_registry().counter(
+        "repro_decode_dispatch_total",
+        "paged_decode_attention dispatches by backend (counted at trace "
+        "time)").inc(backend="interpret" if be == "pallas" and interpret
+                     else be)
     if be == "pallas":
-        return _paged_decode_pallas(
-            q, k_pages, v_pages, page_table, lengths, window=window,
-            softcap=softcap, scale=scale, interpret=interpret,
-            k_scale=k_scale, v_scale=v_scale)
+        def kernel(q, k_pages, v_pages, page_table, lengths, k_scale,
+                   v_scale):
+            return _paged_decode_pallas(
+                q, k_pages, v_pages, page_table, lengths, window=window,
+                softcap=softcap, scale=scale, interpret=interpret,
+                k_scale=k_scale, v_scale=v_scale)
+        args = (q, k_pages, v_pages, page_table, lengths, k_scale, v_scale)
+        if mesh is not None:
+            return run_replicated(kernel, mesh, *args)
+        return kernel(*args)
     return _paged_decode_xla(
         q, k_pages, v_pages, page_table, lengths, window=window,
         softcap=softcap, scale=scale, k_scale=k_scale, v_scale=v_scale)

@@ -81,6 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.block_pattern import BlockPattern
+from ..device import on_tpu
 from ..obs import metrics as _obs_metrics
 from . import csd_spmm, ref
 from .csd_spmm import apply_activation  # noqa: F401 — re-export: layers
@@ -93,24 +94,28 @@ def _count_dispatch(backend: str, form: str) -> None:
     junction *instantiations per compiled executable*, not per-step
     executions — which is the useful number: it says which backend/form
     every compiled program routed each junction through, without putting
-    any op (or host sync) into the traced program itself."""
+    any op (or host sync) into the traced program itself. A Pallas kernel
+    run by the interpreter counts as backend ``interpret``."""
     _obs_metrics.get_registry().counter(
         "repro_junction_dispatch_total",
         "csd_matmul dispatches by backend/form (counted at trace time)",
     ).inc(backend=backend, form=form)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # no backend yet
-        return False
-
-
 def _resolve(backend: str) -> str:
     if backend == "auto":
-        return "pallas" if _on_tpu() else "xla"
+        return "pallas" if on_tpu() else "xla"
     return backend
+
+
+def run_replicated(fn, mesh, *args):
+    """``fn(*args)`` whole on every device of ``mesh``, every operand and
+    result replicated. XLA cannot partition a Mosaic kernel, so under a
+    multi-device mesh a Pallas call that has no sharded form runs inside
+    this ``shard_map`` (sharded operands are gathered at entry)."""
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 # Static pattern arrays are hashed by id for custom_vjp staticness; wrap them
@@ -658,7 +663,6 @@ def _local_pattern(spat, axis):
 
 def _spmd_fwd_call(x, w, b, spat, has_bias, activation, backend, block_m,
                    interpret, mesh, axis, lead, want_aux):
-    from ..compat import shard_map
     batched = w.ndim == 5
     x_spec, w_spec, b_spec, y_spec = _shard_specs(
         batched, has_bias, lead, axis)
@@ -691,8 +695,9 @@ def _spmd_fwd_call(x, w, b, spat, has_bias, activation, backend, block_m,
         return y
 
     out_specs = (y_spec, y_spec) if want_aux else y_spec
-    fn = shard_map(local, mesh=mesh, in_specs=(x_spec, w_spec, b_spec),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(x_spec, w_spec, b_spec),
+                       out_specs=out_specs, check_vma=False)
     return fn(x, w, b)
 
 
@@ -722,7 +727,6 @@ def _spmd_fwd_vjp(x, w, b, spat, has_bias, activation, backend, block_m,
 
 def _spmd_bwd_vjp(spat, has_bias, activation, backend, block_m, interpret,
                   mesh, axis, lead, res, dy):
-    from ..compat import shard_map
     from jax.sharding import PartitionSpec as P
     x, w, b, aux = res
     dy = dy.astype(x.dtype)
@@ -779,7 +783,7 @@ def _spmd_bwd_vjp(spat, has_bias, activation, backend, block_m, interpret,
         return dx, dwl, dbl
 
     dx_spec = P(*lead, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, w_spec, b_spec, y_spec, y_spec),
         out_specs=(dx_spec, w_spec, b_spec), check_vma=False)
@@ -880,7 +884,6 @@ def _quant_matmul_sharded(x, w, w_scale, pattern, bias, activation, backend,
     same contiguous split as the slab (``P(axis, None)`` for the 2-D
     scales, ``P(None, axis, None)`` batched), so each device's local
     scales line up with its local pattern rows."""
-    from ..compat import shard_map
     from jax.sharding import PartitionSpec as P
     if axis not in mesh.axis_names:
         raise ValueError(f"mesh has no axis {axis!r}")
@@ -916,9 +919,9 @@ def _quant_matmul_sharded(x, w, w_scale, pattern, bias, activation, backend,
                 z = z + bb.astype(z.dtype)
             return csd_spmm.apply_activation(z, activation)
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(x_spec, w_spec, s_spec, b_spec),
-                       out_specs=y_spec, check_vma=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(x_spec, w_spec, s_spec, b_spec),
+                           out_specs=y_spec, check_vma=False)
         return fn(xf, w, w_scale, b)
 
     if backend == "pallas":
@@ -991,6 +994,10 @@ def csd_matmul(
     of ``x``'s leading dims (XLA path) so their sharding survives entry.
     Requires ``n_rb % mesh.shape[axis] == 0`` (see ``can_partition``).
 
+    ``mesh`` without ``axis`` (a mesh is installed but this junction
+    cannot shard over it) runs the Pallas kernel whole on every device
+    (``run_replicated``); the XLA path leaves placement to GSPMD.
+
     Quantized form (inference only, no VJP): pass ``w`` as int8 with
     ``w_scale`` per-block f32 scales ``(n_rb, d_in_b)`` (batched:
     ``(E, n_rb, d_in_b)``) from ``core.quant.quantize_slab`` — the slab
@@ -1044,7 +1051,15 @@ def csd_matmul(
     if backend == "dense" and (quant or sharded):
         raise ValueError("backend='dense' supports only the plain/batched "
                          "unquantized junction")
-    _count_dispatch(backend, form)
+    if mesh is not None and not sharded and backend == "pallas":
+        return run_replicated(
+            lambda x, w, b, s: csd_matmul(
+                x, w, pattern, bias=b, activation=activation,
+                backend=backend, dataflow=dataflow, block_m=block_m,
+                interpret=interpret, w_scale=s),
+            mesh, x, w, bias, w_scale)
+    _count_dispatch("interpret" if backend == "pallas" and interpret
+                    else backend, form)
     if quant:
         if w.dtype != jnp.int8:
             raise ValueError(

@@ -1,9 +1,25 @@
-"""Production meshes. A FUNCTION, not a module constant, so importing this
-module never touches jax device state (the dry-run must set XLA_FLAGS before
-any jax initialization)."""
+"""Every device mesh of the repo is built here. FUNCTIONS, not module
+constants, so importing this module never touches jax device state (the
+dry-run must set XLA_FLAGS before any jax initialization).
+
+Axes are ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+which the sharded junctions' ``shard_map`` fails with "Unexpected XLA
+sharding override". The repo places arrays with ``NamedSharding`` and
+sharding constraints, which is what ``Auto`` axes mean.
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """A mesh of ``shape`` over ``axes`` (Auto axis types). ``devices``
+    defaults to the first ``prod(shape)`` devices JAX reports."""
+    shape, axes = tuple(shape), tuple(axes)
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,9 +39,4 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small host-platform meshes)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes, devices=devices[:need])

@@ -132,8 +132,10 @@ def main():
     args = ap.parse_args()
 
     from ..configs import get_config
+    from ..device import init_compile_cache
     from ..nn import build_model
 
+    init_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))

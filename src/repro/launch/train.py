@@ -15,8 +15,9 @@ import argparse
 import os
 from functools import partial
 
-import jax
 import numpy as np
+
+from .mesh import make_mesh
 
 
 def main():
@@ -45,11 +46,13 @@ def main():
 
     from ..configs import get_config
     from ..data import BigramLM
+    from ..device import init_compile_cache
     from ..nn import build_model
     from ..nn.common import SparsityConfig
     from ..optim import AdamWConfig
     from ..train import RestartLoop, RestartPolicy, Trainer, TrainerConfig
 
+    init_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
     if args.rho is not None:
         sp = cfg.sparsity
@@ -63,7 +66,7 @@ def main():
         shape_s, axes_s = args.mesh.split("x ")
         shape = tuple(int(x) for x in shape_s.split(","))
         axes = tuple(axes_s.split(","))
-        mesh = jax.make_mesh(shape, axes)
+        mesh = make_mesh(shape, axes)
 
     if args.metrics_jsonl:
         from ..obs import get_registry

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import shard_map
 from ..serving import kv_cache as paged_kv
 from .common import ModelConfig, current_mesh, logical_to_spec, shard
 from .layers import Linear, RMSNorm, apply_rope
@@ -202,7 +201,7 @@ def seq_parallel_attention(
         return chunked_attention(qg_l, k_l, v_l,
                                  q_offset=idx * s_local, **kw)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv),
         out_specs=spec_q, check_vma=False)
@@ -408,7 +407,7 @@ class Attention:
                 qg, k_pages, v_pages, page_table, lengths,
                 window=self.window, softcap=cfg.logit_softcap,
                 scale=scale, backend=backend, interpret=interpret,
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale=k_scale, v_scale=v_scale, mesh=current_mesh())
             o = o.reshape(b, 1, self.h * self.dh).astype(x.dtype)
         else:
             # chunk prefill: gather this batch row's logical KV view and

@@ -83,13 +83,16 @@ def junction_shard_ctx(pattern):
 
 
 def junction_shard_kwargs(pattern) -> dict:
-    """``csd_matmul`` kwargs selecting the sharded junction path, or ``{}``
-    when it doesn't apply — the ONE place the gating decision plus kwarg
-    spelling lives, shared by every junction call site (``nn.layers``,
-    ``nn.ffn``, ``core.sparse_linear``)."""
+    """``csd_matmul`` kwargs selecting the sharded junction path — the ONE
+    place the gating decision plus kwarg spelling lives, shared by every
+    junction call site (``nn.layers``, ``nn.ffn``, ``core.sparse_linear``).
+    With a mesh installed but no shard for this junction, only the mesh
+    is passed (the kernel then runs whole on every device); with no mesh,
+    ``{}``."""
     ctx = junction_shard_ctx(pattern)
     if ctx is None:
-        return {}
+        mesh = _MESH.get()
+        return {} if mesh is None or pattern is None else {"mesh": mesh}
     return {"mesh": ctx[0], "axis": ctx[1]}
 
 

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import shard_map
 from ..core.block_pattern import fit_block_pattern
 from ..kernels import ops as kops
 from .common import (ModelConfig, MoEConfig, current_mesh,
@@ -154,9 +153,9 @@ class MoE:
             fan_in = pat.d_in_b * pat.block_in
             return jax.random.normal(
                 key, (E, pat.n_rb, pat.d_in_b, pat.block_in, pat.block_out),
-                self.pd) * np.sqrt(1.0 / fan_in)
+                self.pd) * float(np.sqrt(1.0 / fan_in))
         return jax.random.normal(key, (E, n_in, n_out), self.pd) \
-            * np.sqrt(1.0 / n_in)
+            * float(np.sqrt(1.0 / n_in))
 
     # expert weights are stored stacked: (E, d, d_e) / (E, d_e, d) dense,
     # (E, n_rb, d_in_b, bL, bR) when the junction is pre-defined sparse
@@ -166,7 +165,7 @@ class MoE:
         E = mc.n_routed
         p = {
             "router": jax.random.normal(ks[0], (d, E), self.pd)
-            * np.sqrt(1.0 / d),
+            * float(np.sqrt(1.0 / d)),
             "up": self._expert_w(ks[1], self.up_pat, d, d_e, E),
             "gate": self._expert_w(ks[2], self.gate_pat, d, d_e, E),
             "down": self._expert_w(ks[3], self.down_pat, d_e, d, E),
@@ -381,7 +380,7 @@ class MoE:
             in_specs = in_specs + (P(ep_axis, None, None),) * 3
             operands += [params["up_scale"], params["gate_scale"],
                          params["down_scale"]]
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=mesh, in_specs=in_specs,
             out_specs=(x_spec, {n: P() for n in ("moe_lb", "moe_z")}),
             check_vma=False)

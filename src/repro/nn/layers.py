@@ -65,10 +65,10 @@ class Linear:
             fan_in = bp.d_in_b * bp.block_in
             w = jax.random.normal(
                 key, (bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out),
-                self.dtype) * np.sqrt(1.0 / fan_in)
+                self.dtype) * float(np.sqrt(1.0 / fan_in))
         else:
             w = jax.random.normal(key, (self.n_in, self.n_out),
-                                  self.dtype) * np.sqrt(1.0 / self.n_in)
+                                  self.dtype) * float(np.sqrt(1 / self.n_in))
         p = {"w": w}
         if self.bias:
             p["b"] = jnp.zeros((self.n_out,), self.dtype)
@@ -161,7 +161,7 @@ class Embedding:
 
     def init(self, key: jax.Array) -> dict:
         w = jax.random.normal(key, (self.vocab, self.dim), self.dtype)
-        return {"table": w * (1.0 / np.sqrt(self.dim))}
+        return {"table": w * float(1.0 / np.sqrt(self.dim))}
 
     def spec(self) -> dict:
         # NOTE: the table's model dim gets its own logical name — sharding
@@ -191,7 +191,6 @@ class Embedding:
         """
         from jax.sharding import PartitionSpec as P
 
-        from ..compat import shard_map
         from .common import current_mesh, logical_to_spec
 
         mesh = current_mesh()
@@ -214,7 +213,7 @@ class Embedding:
             g = jnp.where(ok[..., None], g, jnp.zeros((), g.dtype))
             return jax.lax.psum(g, vax)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh, in_specs=(spec_t, spec_i),
             out_specs=P(spec_i[0], None, None), check_vma=False)
         return fn(t, tokens)

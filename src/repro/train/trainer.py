@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..nn.common import mesh_context
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
@@ -220,7 +219,7 @@ class Trainer:
         else:
             mesh = self.mesh
             spec = jax.tree.map(lambda _: P(), params)
-            fn = shard_map(
+            fn = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=(spec, spec, spec, spec),
                 out_specs=(spec, spec, spec, spec), check_vma=False)

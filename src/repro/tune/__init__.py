@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..device import on_tpu
 from ..obs import metrics as _obs_metrics
 from . import cache as _cache
 from .cache import (SCHEMA_VERSION, TuneCache, blocks_enabled,  # noqa: F401
@@ -58,14 +59,6 @@ def _count_decision(op: str, entry: dict) -> None:
           dataflow=entry.get("dataflow", "-"))
 
 
-def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 def decide_junction(*, m: int, n_in: int, n_out: int, rho: float,
                     E: int = 0, dtype: str = "float32",
                     quant: bool = False, form: str = "plain",
@@ -91,7 +84,7 @@ def decide_junction(*, m: int, n_in: int, n_out: int, rho: float,
     allowed = {"pallas", "xla"} if (quant or "sharded" in form) \
         else {"pallas", "xla", "dense"}
     be = ent.get("backend")
-    if be not in allowed or (be == "pallas" and not _on_tpu()) \
+    if be not in allowed or (be == "pallas" and not on_tpu()) \
             or ent.get("dataflow", "gather") not in ("gather", "scatter"):
         _count("csd_spmm", "invalid")
         return None
@@ -122,7 +115,7 @@ def decide_decode(*, b: int, h_kv: int, groups: int, head_dim: int,
             pool=int(pool), quant=bool(quant), dtype=str(dtype)))
         return None
     be = ent.get("backend")
-    if be not in ("pallas", "xla") or (be == "pallas" and not _on_tpu()):
+    if be not in ("pallas", "xla") or (be == "pallas" and not on_tpu()):
         _count("paged_decode", "invalid")
         return None
     _count("paged_decode", "hit")

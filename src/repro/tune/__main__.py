@@ -146,7 +146,7 @@ def main(argv=None) -> int:
                     help="comma-separated M regimes (tokens) to trace")
     ap.add_argument("--cache", default=None,
                     help="cache file (default: REPRO_TUNE_CACHE or "
-                         "~/.cache/repro/tune_cache.json)")
+                         "<checkout>/.cache/tune_cache.json)")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--repeats", type=int, default=2)
     ap.add_argument("--blocks", action="store_true",

@@ -15,7 +15,8 @@ Contracts (ISSUE 10):
   it, so a concurrent reader sees either the old or the new cache, never a
   torn one;
 * env-overridable path — ``REPRO_TUNE_CACHE=<path>`` relocates the file
-  (default ``$XDG_CACHE_HOME/repro/tune_cache.json``);
+  (default ``.cache/tune_cache.json`` in the checkout, gitignored: a stale
+  file elsewhere on the machine cannot redirect dispatch);
 * kill switch — ``REPRO_TUNE_DISABLE=1`` makes every lookup miss, which
   restores today's deterministic ``_resolve`` heuristic exactly;
 * corruption tolerance — unreadable / truncated / non-JSON / wrong-schema
@@ -56,21 +57,16 @@ def default_path() -> str:
     p = os.environ.get(ENV_PATH)
     if p:
         return p
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "repro", "tune_cache.json")
+    from ..device import CACHE_DIR
+    return str(CACHE_DIR / "tune_cache.json")
 
 
 def device_kind() -> str:
     """Cache-key device id: platform plus hardware kind (decisions measured
     on one device class must not leak onto another)."""
-    try:
-        import jax
-        d = jax.devices()[0]
-        kind = str(getattr(d, "device_kind", "") or d.platform)
-        return f"{d.platform}:{kind}".replace(" ", "_")
-    except Exception:  # no backend initialised — key still forms
-        return "unknown"
+    import jax
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}".replace(" ", "_")
 
 
 def m_bucket(m: int) -> int:

@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional
 
+from ..device import on_tpu
 from ..obs import metrics as _obs_metrics
 from ..obs import trace as _obs_trace
 from . import cache as _cache
@@ -39,14 +40,6 @@ from . import certify as _certify
 SKINNY_M = 32
 
 PALLAS_BLOCK_MS = (128, 256)
-
-
-def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,7 +64,7 @@ def junction_candidates(*, quant: bool = False, sharded: bool = False,
         # dense-ref escape hatch: densify the slab (static take) + one
         # GEMM. No sharded/quant form — those contracts are slab-only.
         cands.append(Candidate("dense"))
-    if _on_tpu() or interpret_pallas:
+    if on_tpu() or interpret_pallas:
         for bm in PALLAS_BLOCK_MS:
             cands.append(Candidate("pallas", "gather", bm))
     return cands
@@ -80,7 +73,7 @@ def junction_candidates(*, quant: bool = False, sharded: bool = False,
 def _heuristic_candidate() -> Candidate:
     """What today's static ``_resolve("auto")`` would pick — the baseline
     every tuned decision is compared against."""
-    return Candidate("pallas" if _on_tpu() else "xla", "gather", 128)
+    return Candidate("pallas" if on_tpu() else "xla", "gather", 128)
 
 
 def _reg():
@@ -164,7 +157,7 @@ def bench_junction(spec: dict, *, cache: Optional[_cache.TuneCache] = None,
                     "pallas candidates rejected by SL101-SL105, by code",
                 ).inc(codes=",".join(info["rejected"]))
                 continue
-        interpret = cand.backend == "pallas" and not _on_tpu()
+        interpret = cand.backend == "pallas" and not on_tpu()
         kw = dict(backend=cand.backend, dataflow=cand.dataflow,
                   block_m=cand.block_m, interpret=interpret)
         try:
@@ -269,12 +262,12 @@ def bench_decode(spec: dict, *, cache: Optional[_cache.TuneCache] = None,
     lengths = np.full((b,), used * page - page // 2, np.int32)
     table, lengths = jnp.asarray(table), jnp.asarray(lengths)
 
-    backends = ["xla"] + (["pallas"] if (_on_tpu() or interpret_pallas)
+    backends = ["xla"] + (["pallas"] if (on_tpu() or interpret_pallas)
                           else [])
     results: dict = {}
     best = None
     for be in backends:
-        interpret = be == "pallas" and not _on_tpu()
+        interpret = be == "pallas" and not on_tpu()
         fn = jax.jit(lambda q, kp, vp, t, ln, be=be, i=interpret:
                      paged_decode_attention(
                          q, kp, vp, t, ln, backend=be, interpret=i,
@@ -292,7 +285,7 @@ def bench_decode(spec: dict, *, cache: Optional[_cache.TuneCache] = None,
             best = (info["us_fwd"], be)
     if best is None:
         raise RuntimeError(f"no runnable decode candidate for {key}")
-    h = "pallas" if _on_tpu() else "xla"
+    h = "pallas" if on_tpu() else "xla"
     h_us = results.get(h, {}).get("us_fwd", best[0])
     entry = {
         "backend": best[1],

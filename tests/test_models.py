@@ -115,3 +115,28 @@ def test_exact_published_dimensions():
     sm = get_config("seamless_m4t_medium")
     assert (sm.enc_dec.n_encoder_layers, sm.d_model, sm.vocab_size) == \
         (12, 1024, 256206)
+
+
+@pytest.mark.parametrize("arch", ["qwen2_7b", "granite_moe_1b_a400m"])
+def test_param_dtype_is_the_stored_dtype(arch):
+    """``param_dtype`` decides what init stores: bf16 parameters are what
+    lets qwen2_7b's published widths fit one 16 GB chip."""
+    cfg = get_config(arch, smoke=True).with_(param_dtype="bfloat16")
+    params = build_model(cfg).init(jax.random.key(0))
+    assert {str(x.dtype) for x in jax.tree.leaves(params)} == {"bfloat16"}
+
+
+def test_scanned_stack_init_matches_per_layer_init():
+    """The stacked (scanned) parameters are each group's own block init
+    under its own key — vmapping the init changes nothing."""
+    cfg = get_config("qwen2_7b", smoke=True)
+    model = build_model(cfg)
+    stack = model.stack
+    p = stack.init(jax.random.key(3))
+    keys = jax.random.split(jax.random.key(3), 4096)[len(stack.prologue):]
+    for u, blk in enumerate(stack.unit_blocks):
+        for g in range(stack.n_groups):
+            want = blk.init(keys[u * stack.n_groups + g])
+            got = jax.tree.map(lambda x: x[g], p["scan"][u])
+            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+                np.testing.assert_array_equal(a, b)

@@ -38,8 +38,11 @@ def run_sub(code: str, devices: int = 8) -> str:
         "import os\n"
         f"os.environ['XLA_FLAGS'] = "
         f"'--xla_force_host_platform_device_count={devices}'\n"
+        "from repro.launch.mesh import make_mesh\n"
         + textwrap.dedent(code))
-    env = dict(os.environ,
+    # the child runs on the CPU's virtual devices, never on an attached
+    # accelerator (a chip belongs to one process at a time)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, env=env, timeout=900)
@@ -211,7 +214,7 @@ _PARITY_PRELUDE = """
 
     bp = make_block_pattern(8 * 4, 16 * 4, 0.5, block_in=4, block_out=4,
                             seed=0)
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     ks = jax.random.split(jax.random.key(0), 3)
 
     def check(mk_args, backends, acts):
@@ -234,6 +237,54 @@ _PARITY_PRELUDE = """
                     worst = max(worst, float(jnp.abs(a - c).max()))
         print("WORST", worst)
 """
+
+
+def test_unsharded_pallas_kernels_run_replicated_under_a_mesh():
+    """A Pallas kernel with no sharded form (a junction whose block-rows
+    do not divide the axis, the paged decode) runs whole on every device
+    of a mesh — XLA cannot partition a Mosaic kernel — and matches the
+    single-device result, forward and gradient."""
+    out = run_sub("""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import make_block_pattern
+    from repro.kernels import ops
+    from repro.kernels.flash_attention import paged_decode_attention
+
+    mesh = make_mesh((4,), ("model",))
+    ks = jax.random.split(jax.random.key(0), 6)
+    bp = make_block_pattern(8 * 4, 6 * 4, 0.5, block_in=4, block_out=4,
+                            seed=0)        # n_rb = 6: no 4-way shard
+    x = jax.random.normal(ks[0], (6, bp.n_in))
+    w = jax.random.normal(ks[1], (bp.n_rb, bp.d_in_b, 4, 4))
+    b = jax.random.normal(ks[2], (bp.n_out,))
+    kw = dict(activation="gelu", backend="pallas", block_m=2,
+              interpret=True)
+    f0 = lambda x, w, b: ops.csd_matmul(x, w, bp, bias=b, **kw)
+    f1 = lambda x, w, b: ops.csd_matmul(x, w, bp, bias=b, mesh=mesh, **kw)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    worst = float(jnp.abs(f0(x, w, b) - jax.jit(f1)(x, w, b)).max())
+    g0 = jax.grad(loss(f0), argnums=(0, 1, 2))(x, w, b)
+    g1 = jax.jit(jax.grad(loss(f1), argnums=(0, 1, 2)))(x, w, b)
+    for a, c in zip(g0, g1):
+        worst = max(worst, float(jnp.abs(a - c).max()))
+
+    # paged decode with the page pool sharded over the mesh
+    q = jax.random.normal(ks[3], (2, 2, 3, 8))
+    kp = jax.random.normal(ks[4], (8, 4, 2, 8))
+    vp = jax.random.normal(ks[5], (8, 4, 2, 8))
+    table = jnp.array([[1, 4, 2, -1], [0, 3, -1, -1]], jnp.int32)
+    lengths = jnp.array([11, 6], jnp.int32)
+    pool = NamedSharding(mesh, P("model"))
+    d0 = paged_decode_attention(q, kp, vp, table, lengths,
+                                backend="pallas", interpret=True)
+    d1 = jax.jit(lambda q, kp, vp: paged_decode_attention(
+        q, kp, vp, table, lengths, backend="pallas", interpret=True,
+        mesh=mesh))(q, jax.device_put(kp, pool), jax.device_put(vp, pool))
+    worst = max(worst, float(jnp.abs(d0 - d1).max()))
+    print("WORST", worst)
+    """, devices=4)
+    assert float(out.split("WORST")[1].split()[0]) < 1e-5, out
 
 
 @pytest.mark.slow
@@ -283,7 +334,7 @@ def test_sharded_quant_matmul_parity_4d_5d_8dev():
 
     bp = make_block_pattern(8 * 4, 16 * 4, 0.5, block_in=4, block_out=4,
                             seed=0)
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     ks = jax.random.split(jax.random.key(0), 3)
     worst = 0.0
     for batched in (False, True):
@@ -337,7 +388,7 @@ def test_sharded_engine_int8_decode_parity_8dev():
                             quant=QuantConfig())
         ref = ServingEngine(model, params, ecfg).run(prompts, 12)
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         eng = ServingEngine(model, params, ecfg, mesh=mesh)
         slabs = [l for l in jax.tree.leaves(eng.params)
                  if l.dtype == jnp.int8]
@@ -401,7 +452,7 @@ def test_sharded_train_step_loss_parity_and_slab_chunking():
                                                         warmup_steps=0))
         p_ref, o_ref, m_ref = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = policy.rules_for("train", 8, mesh, cfg)
         assert rules["slab"] == "model"
         pspec = policy.param_pspecs(model.spec(), rules)
@@ -453,7 +504,7 @@ def test_sharded_checkpoint_roundtrip_8dev():
         model = build_model(cfg)
         params = model.init(jax.random.key(0))
         opt = adam.init(params)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = policy.rules_for("train", 8, mesh, cfg)
         pspec = policy.param_pspecs(model.spec(), rules)
         p_sh = policy.named(mesh, pspec, params)
@@ -512,7 +563,7 @@ def test_sharded_engine_decode_token_parity_8dev():
                             prefill_chunk=8, backend="xla")
         ref = ServingEngine(model, params, ecfg).run(prompts, 12)
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         eng = ServingEngine(model, params, ecfg, mesh=mesh)
         assert eng.rules["slab"] == "model"
         kp = eng.cache["scan"][0]["self"]["k_pages"]

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import policy
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
@@ -24,8 +25,11 @@ def run_sub(code: str, devices: int = 8) -> str:
         "import os\n"
         f"os.environ['XLA_FLAGS'] = "
         f"'--xla_force_host_platform_device_count={devices}'\n"
+        "from repro.launch.mesh import make_mesh\n"
         + textwrap.dedent(code))
-    env = dict(os.environ,
+    # the child runs on the CPU's virtual devices, never on an attached
+    # accelerator (a chip belongs to one process at a time)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, env=env, timeout=900)
@@ -71,7 +75,7 @@ def test_mamba_rules_fold_model_into_batch():
 
 
 def test_sanitize_drops_indivisible_dims():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     class S:
         shape = (37, 64)
@@ -135,7 +139,7 @@ def test_distributed_train_step_runs_and_matches_single_device():
                                                         warmup_steps=0))
         p_ref, o_ref, m_ref = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = policy.rules_for("train", 8, mesh, cfg)
         pspec = policy.param_pspecs(model.spec(), rules)
         p_sh = policy.named(mesh, pspec, params)
@@ -176,7 +180,7 @@ def test_moe_shardmap_matches_local():
         x = jax.random.normal(jax.random.key(1), (4, 8, 32))
         y_local, _ = moe(params, x)   # no mesh -> local path
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = policy.rules_for("train", 4, mesh, cfg)
         with mesh, mesh_context(mesh, rules):
             y_sm, aux = jax.jit(lambda p, x: moe(p, x))(params, x)
@@ -233,7 +237,7 @@ def test_sparse_moe_shardmap_matches_local_and_dense_oracle():
             return jnp.sum(y ** 2)
         g_s = jax.grad(loss)(params)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = policy.rules_for("train", 4, mesh, cfg)
         with mesh, mesh_context(mesh, rules):
             y_sm, aux = jax.jit(lambda p, x: moe(p, x))(params, x)
@@ -266,7 +270,7 @@ def test_seq_parallel_attention_matches_unsharded():
         batch = {"tokens": tokens, "labels": tokens}
         h_ref, _, _ = model.forward(params, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = policy.rules_for("train", 4, mesh, cfg)
         with mesh, mesh_context(mesh, rules):
             h_sh, _, _ = jax.jit(

@@ -13,10 +13,10 @@ from jax.sharding import PartitionSpec as P
 from repro.analysis import grid_pass, jaxpr_pass, pattern_pass
 from repro.analysis.capture import CapturedLaunch, capture_launch
 from repro.analysis.findings import Finding, Report, apply_suppressions
-from repro.compat import shard_map
 from repro.core import sparsity
 from repro.core.block_pattern import (fit_block_pattern, make_block_pattern,
                                       partition_pattern)
+from repro.launch.mesh import make_mesh
 
 
 def _codes(findings):
@@ -105,12 +105,12 @@ def test_capture_records_real_launch():
 
 
 def test_shard_map_missing_psum_flags_sl205():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     def broken(x):
-        return shard_map(lambda xl: xl.sum(axis=0), mesh=mesh,
-                         in_specs=P("model"), out_specs=P(),
-                         check_vma=False)(x)
+        return jax.shard_map(lambda xl: xl.sum(axis=0), mesh=mesh,
+                             in_specs=P("model"), out_specs=P(),
+                             check_vma=False)(x)
 
     traced = jax.jit(broken).trace(jax.ShapeDtypeStruct((4, 8),
                                                         jnp.float32))
@@ -119,10 +119,10 @@ def test_shard_map_missing_psum_flags_sl205():
 
 
 def test_shard_map_with_psum_is_clean():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     def ok(x):
-        return shard_map(
+        return jax.shard_map(
             lambda xl: jax.lax.psum(xl.sum(axis=0), "model"), mesh=mesh,
             in_specs=P("model"), out_specs=P(), check_vma=False)(x)
 

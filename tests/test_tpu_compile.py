@@ -1,0 +1,157 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers a kernel at qwen2_7b's published widths in
+bf16 and hands it to the TPU compiler for a chip that is described, not
+attached. The compiler refuses what interpret mode accepts (block shapes
+off the (8, 128) tiling, comparisons the vector unit lacks), so these
+tests guard the chip path at no chip time.
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process may load the TPU library, and with several test
+workers every worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.block_pattern import fit_block_pattern
+from repro.kernels import csd_spmm, ops
+from repro.kernels.flash_attention import paged_decode_attention
+
+QWEN = get_config("qwen2_7b")
+TOKENS = 256          # two 128-row tiles
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """Compiles for a described chip are written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_compile_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _pattern(junction):
+    """qwen2_7b's FFN junction patterns at published widths."""
+    sp = QWEN.sparsity
+    if junction == "up":
+        return fit_block_pattern(QWEN.d_model, QWEN.d_ff, sp.rho_ffn[0], sp)
+    return fit_block_pattern(QWEN.d_ff, QWEN.d_model, sp.rho_ffn[1], sp)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+@pytest.mark.parametrize("junction", ["up", "down"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_fwd_with_bias_compiles(one_chip, junction, quant):
+    bp = _pattern(junction)
+    w_shape = (bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out)
+    x = _spec((TOKENS, bp.n_in), BF16, one_chip)
+    b = _spec((bp.n_out,), BF16, one_chip)
+    if quant:
+        w = _spec(w_shape, jnp.int8, one_chip)
+        s = _spec(w_shape[:2], jnp.float32, one_chip)
+        _compile(lambda x, w, s, b: csd_spmm.csd_spmm_fwd(
+            x, w, bp.block_idx, bias=b, activation="relu", w_scale=s),
+            x, w, s, b)
+    else:
+        w = _spec(w_shape, BF16, one_chip)
+        _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
+            x, w, bp.block_idx, bias=b, activation="relu"), x, w, b)
+
+
+@pytest.mark.parametrize("activation", ["relu", "gelu"])
+def test_fwd_dx_dw_gradient_compiles(one_chip, activation):
+    bp = _pattern("up")
+    x = _spec((TOKENS, bp.n_in), BF16, one_chip)
+    w = _spec((bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out), BF16,
+              one_chip)
+    b = _spec((bp.n_out,), BF16, one_chip)
+
+    def loss(x, w, b):
+        y = ops.csd_matmul(x, w, bp, bias=b, activation=activation,
+                           backend="pallas")
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b)
+    # forward, dx and dw each lower to their own kernel
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_batched_fwd_and_gradient_compile(one_chip):
+    experts = 4
+    bp = _pattern("up")
+    x = _spec((experts, TOKENS, bp.n_in), BF16, one_chip)
+    w = _spec((experts, bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out),
+              BF16, one_chip)
+    b = _spec((experts, bp.n_out), BF16, one_chip)
+    _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
+        x, w, bp.block_idx, bias=b, activation="relu"), x, w, b)
+
+    def loss(x, w, b):
+        y = ops.csd_matmul(x, w, bp, bias=b, activation="gelu",
+                           backend="pallas")
+        return jnp.sum(y.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_compiles(one_chip, quant):
+    slots, page_size, pages_per_seq, pool = 4, 16, 40, 161
+    hkv, dh = QWEN.n_kv_heads, QWEN.head_dim
+    groups = QWEN.n_heads // hkv
+    q = _spec((slots, hkv, groups, dh), BF16, one_chip)
+    kv_dtype = jnp.int8 if quant else BF16
+    kv = _spec((pool, page_size, hkv, dh), kv_dtype, one_chip)
+    pt = _spec((slots, pages_per_seq), jnp.int32, one_chip)
+    ln = _spec((slots,), jnp.int32, one_chip)
+    if quant:
+        sc = _spec((pool, page_size), jnp.float32, one_chip)
+        _compile(lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
+            q, k, v, pt, ln, backend="pallas", k_scale=ks, v_scale=vs),
+            q, kv, kv, pt, ln, sc, sc)
+    else:
+        _compile(lambda q, k, v, pt, ln: paged_decode_attention(
+            q, k, v, pt, ln, backend="pallas"), q, kv, kv, pt, ln)
+
+
+def test_junction_widths_are_published():
+    """The compiles above are at the published widths, not a smoke cut."""
+    up, down = _pattern("up"), _pattern("down")
+    assert (up.n_in, up.n_out) == (3584, 18944)
+    assert (down.n_in, down.n_out) == (18944, 3584)
+    assert (up.block_in, up.block_out) == (256, 512)
