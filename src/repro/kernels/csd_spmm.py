@@ -52,6 +52,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .names import pallas_names
+
 
 # ---------------------------------------------------------------------------
 # Forward: y[m, rb] = sum_f x[m, block_idx[rb, f]] @ w[rb, f]
@@ -297,6 +299,7 @@ def _csd_spmm_fwd_quant(x, w, w_scale, block_idx, *, bias, activation,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n_rb * br), jnp.float32),
         interpret=interpret,
+        **pallas_names("csd_spmm_fwd_int8"),
     )(*operands)
     return out.astype(x.dtype)
 
@@ -339,6 +342,7 @@ def _csd_spmm_fwd_quant_batched(x, w, w_scale, block_idx, *, bias,
         ),
         out_shape=jax.ShapeDtypeStruct((e, m, n_rb * br), jnp.float32),
         interpret=interpret,
+        **pallas_names("csd_spmm_fwd_int8_batched"),
     )(*operands)
     return out.astype(x.dtype)
 
@@ -384,6 +388,7 @@ def _csd_spmm_fwd_batched(x, w, block_idx, *, bias, activation, save_preact,
         ),
         out_shape=(out_shape, out_shape) if save_preact else out_shape,
         interpret=interpret,
+        **pallas_names("csd_spmm_fwd_batched"),
     )(*operands)
     if save_preact:
         y, z = out
@@ -488,6 +493,7 @@ def csd_spmm_fwd(
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        **pallas_names("csd_spmm_fwd"),
     )(*operands)
     if save_preact:
         y, z = out
@@ -624,6 +630,7 @@ def csd_spmm_dx(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        **pallas_names("csd_spmm_dx_batched" if batched else "csd_spmm_dx"),
     )(*operands)
     return dx.astype(dy.dtype)
 
@@ -766,6 +773,7 @@ def csd_spmm_dw(
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        **pallas_names("csd_spmm_dw_batched" if batched else "csd_spmm_dw"),
     )(*operands)
     if want_db:
         dw, db = out

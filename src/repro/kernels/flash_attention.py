@@ -26,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import metrics as obs_metrics
+from .names import pallas_names
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -142,6 +143,7 @@ def flash_attention(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        **pallas_names("flash_attention"),
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2)
 
@@ -271,6 +273,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=interpret,
+        **pallas_names("paged_decode_attention"),
     )(*operands)
 
 

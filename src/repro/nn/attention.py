@@ -286,6 +286,7 @@ class Attention:
 
     # -- qkv ----------------------------------------------------------------
 
+    @jax.named_scope("attn/proj")
     def _qkv(self, params, x, x_kv, positions):
         cfg = self.cfg
         b = x.shape[0]
@@ -301,6 +302,10 @@ class Attention:
             k = apply_rope(k, positions, cfg.rope_theta)
         return q, k, v
 
+    @jax.named_scope("attn/proj")
+    def _out(self, params, o):
+        return self.wo(params["o"], o)
+
     # -- full-sequence (train / prefill) -------------------------------------
 
     def __call__(self, params: dict, x: jax.Array, positions: jax.Array,
@@ -315,13 +320,14 @@ class Attention:
         v = shard(v, "batch", None, None, None)
         qg = q.reshape(b, sq, self.kv, self.groups, self.dh)
         causal = causal and not self.cross
-        o = seq_parallel_attention(
-            qg, k, v, causal=causal, window=self.window,
-            softcap=cfg.logit_softcap, chunk=cfg.attn_chunk,
-            kv_chunk=cfg.attn_kv_chunk, scale=self.dh ** -0.5)
+        with jax.named_scope("attn/core"):
+            o = seq_parallel_attention(
+                qg, k, v, causal=causal, window=self.window,
+                softcap=cfg.logit_softcap, chunk=cfg.attn_chunk,
+                kv_chunk=cfg.attn_kv_chunk, scale=self.dh ** -0.5)
         o = o.reshape(b, sq, self.h * self.dh)
         o = shard(o, "batch", "seq", None)
-        return self.wo(params["o"], o), {"k": k, "v": v}
+        return self._out(params, o), {"k": k, "v": v}
 
     # -- single-token decode --------------------------------------------------
 
@@ -345,13 +351,14 @@ class Attention:
                 cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1)
             cache = {"k": k, "v": v}
         qg = q.reshape(b, 1, self.kv, self.groups, self.dh)
-        o = decode_attention(
-            qg, k.astype(q.dtype), v.astype(q.dtype),
-            pos=pos if not self.cross else k.shape[1] - 1,
-            window=self.window if not self.cross else None,
-            softcap=self.cfg.logit_softcap, scale=self.dh ** -0.5)
+        with jax.named_scope("attn/core"):
+            o = decode_attention(
+                qg, k.astype(q.dtype), v.astype(q.dtype),
+                pos=pos if not self.cross else k.shape[1] - 1,
+                window=self.window if not self.cross else None,
+                softcap=self.cfg.logit_softcap, scale=self.dh ** -0.5)
         o = o.reshape(b, 1, self.h * self.dh)
-        return self.wo(params["o"], o), cache
+        return self._out(params, o), cache
 
     # -- paged serving step (decode or chunked prefill) -----------------------
 
@@ -403,45 +410,47 @@ class Attention:
         if c == 1:
             from ..kernels.flash_attention import paged_decode_attention
             qg = q.reshape(b, self.kv, self.groups, self.dh)
-            o = paged_decode_attention(
-                qg, k_pages, v_pages, page_table, lengths,
-                window=self.window, softcap=cfg.logit_softcap,
-                scale=scale, backend=backend, interpret=interpret,
-                k_scale=k_scale, v_scale=v_scale, mesh=current_mesh())
+            with jax.named_scope("attn/decode"):
+                o = paged_decode_attention(
+                    qg, k_pages, v_pages, page_table, lengths,
+                    window=self.window, softcap=cfg.logit_softcap,
+                    scale=scale, backend=backend, interpret=interpret,
+                    k_scale=k_scale, v_scale=v_scale, mesh=current_mesh())
             o = o.reshape(b, 1, self.h * self.dh).astype(x.dtype)
         else:
             # chunk prefill: gather this batch row's logical KV view and
             # run masked grouped attention (causal against everything
             # already in the pages, including this just-written chunk)
-            k = paged_kv.gather_kv(k_pages, page_table)
-            v = paged_kv.gather_kv(v_pages, page_table)
-            if quant:
-                ks = paged_kv.gather_scales(k_scale, page_table)
-                vs = paged_kv.gather_scales(v_scale, page_table)
-                k = k.astype(jnp.float32) * ks[:, :, None, None]
-                v = v.astype(jnp.float32) * vs[:, :, None, None]
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-            qg = q.reshape(b, c, self.kv, self.groups, self.dh)
-            logits = jnp.einsum("bqhgd,bkhd->bhgqk",
-                                qg.astype(jnp.float32) * scale,
-                                k.astype(jnp.float32))
-            logits = _softcap(logits, cfg.logit_softcap)
-            kpos = jnp.arange(k.shape[1])
-            mask = kpos[None, None] <= positions[:, :, None]   # (B, C, S)
-            if self.window is not None:
-                mask &= kpos[None, None] > positions[:, :, None] \
-                    - self.window
-            mask &= valid[..., None]
-            logits = jnp.where(mask[:, None, None], logits, _NEG_INF)
-            m = jnp.max(logits, axis=-1, keepdims=True)
-            p = jnp.exp(logits - jnp.maximum(m, _NEG_INF / 2))
-            p = jnp.where(m > _NEG_INF / 2, p, 0.0)
-            l = jnp.sum(p, axis=-1, keepdims=True)
-            p = p / jnp.where(l == 0.0, 1.0, l)
-            o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
+            with jax.named_scope("attn/core"):
+                k = paged_kv.gather_kv(k_pages, page_table)
+                v = paged_kv.gather_kv(v_pages, page_table)
+                if quant:
+                    ks = paged_kv.gather_scales(k_scale, page_table)
+                    vs = paged_kv.gather_scales(v_scale, page_table)
+                    k = k.astype(jnp.float32) * ks[:, :, None, None]
+                    v = v.astype(jnp.float32) * vs[:, :, None, None]
+                k = k.astype(q.dtype)
+                v = v.astype(q.dtype)
+                qg = q.reshape(b, c, self.kv, self.groups, self.dh)
+                logits = jnp.einsum("bqhgd,bkhd->bhgqk",
+                                    qg.astype(jnp.float32) * scale,
+                                    k.astype(jnp.float32))
+                logits = _softcap(logits, cfg.logit_softcap)
+                kpos = jnp.arange(k.shape[1])
+                mask = kpos[None, None] <= positions[:, :, None]   # (B, C, S)
+                if self.window is not None:
+                    mask &= kpos[None, None] > positions[:, :, None] \
+                        - self.window
+                mask &= valid[..., None]
+                logits = jnp.where(mask[:, None, None], logits, _NEG_INF)
+                m = jnp.max(logits, axis=-1, keepdims=True)
+                p = jnp.exp(logits - jnp.maximum(m, _NEG_INF / 2))
+                p = jnp.where(m > _NEG_INF / 2, p, 0.0)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                p = p / jnp.where(l == 0.0, 1.0, l)
+                o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
             o = o.reshape(b, c, self.h * self.dh).astype(x.dtype)
-        out = self.wo(params["o"], o)
+        out = self._out(params, o)
         new_cache = {"k_pages": k_pages, "v_pages": v_pages}
         if quant:
             new_cache["k_scale"] = k_scale

@@ -87,6 +87,7 @@ class FFN:
             s["gate"] = self.gate.spec()
         return s
 
+    @jax.named_scope("ffn")
     def __call__(self, params: dict, x: jax.Array) -> jax.Array:
         fused = _FUSABLE.get(self.cfg.act)
         if self.gate is not None:
@@ -204,6 +205,7 @@ class MoE:
 
     # -- routing (shared by both impls) -------------------------------------
 
+    @jax.named_scope("moe/router")
     def _route(self, params, x2d):
         """x2d: (T, d) -> gates (T,k), ids (T,k), aux losses."""
         mc = self.mc
@@ -245,6 +247,7 @@ class MoE:
         y = jnp.einsum("ecd,edf->ecf", xe, w.astype(cdt))
         return kops.apply_activation(y, activation)
 
+    @jax.named_scope("moe/experts")
     def _expert_ffn(self, up, gate, down, xe, sharded=False,
                     scales=(None, None, None)):
         """xe: (E_loc, C, d) -> (E_loc, C, d), batched over experts — the
@@ -275,8 +278,11 @@ class MoE:
 
     # -- local (single-shard) sort-based dispatch ----------------------------
 
+    @jax.named_scope("moe/dispatch")
     def _dispatch_local(self, x2d, gates, ids, capacity):
-        """Build (E, C) token-index and gate buffers from local routing.
+        """Build (E, C) token-index and gate buffers from local routing,
+        and gather each expert's (C, d) input rows; returns (xe, buf_tok,
+        buf_gate).
 
         Gather form: after the stable sort by expert id, expert ``e``'s
         assignments occupy sorted rows ``[starts[e], starts[e]+counts[e])``
@@ -301,8 +307,11 @@ class MoE:
         buf_tok = jnp.where(valid, jnp.take(stok, gidx),
                             jnp.int32(T))
         buf_gate = jnp.where(valid, jnp.take(sgate, gidx), 0.0)
-        return buf_tok, buf_gate
+        xp = jnp.concatenate([x2d, jnp.zeros((1, x2d.shape[1]), x2d.dtype)],
+                             axis=0)
+        return xp[buf_tok], buf_tok, buf_gate  # xe: (E, C, d)
 
+    @jax.named_scope("moe/combine")
     def _combine_local(self, ye, buf_tok, buf_gate, T):
         """Weight expert outputs by their gates and segment-sum them back
         onto token rows (row T is the dispatch-padding sink)."""
@@ -314,16 +323,14 @@ class MoE:
 
     def _moe_local(self, params, x2d, capacity):
         gates, ids, aux = self._route(params, x2d)
-        buf_tok, buf_gate = self._dispatch_local(x2d, gates, ids, capacity)
-        T, d = x2d.shape
-        xp = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)], axis=0)
-        xe = xp[buf_tok]  # (E, C, d)
+        xe, buf_tok, buf_gate = self._dispatch_local(x2d, gates, ids,
+                                                     capacity)
         ye = self._expert_ffn(params["up"], params["gate"], params["down"],
                               xe, sharded=True,
                               scales=(params.get("up_scale"),
                                       params.get("gate_scale"),
                                       params.get("down_scale")))
-        return self._combine_local(ye, buf_tok, buf_gate, T), aux
+        return self._combine_local(ye, buf_tok, buf_gate, x2d.shape[0]), aux
 
     # -- expert-parallel shard_map implementation ----------------------------
 
@@ -355,18 +362,19 @@ class MoE:
             x2d = xl.reshape(t_loc, d)
             gates, ids, aux = self._route({"router": router}, x2d)
             c_src = self.capacity(t_loc)
-            buf_tok, buf_gate = self._dispatch_local(x2d, gates, ids, c_src)
-            xp = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)], axis=0)
-            xe = xp[buf_tok]  # (E, C_src, d)
-            # ship capacity buffers to expert owners: E = n_ep * e_loc
-            xr = jax.lax.all_to_all(
-                xe.reshape(n_ep, e_loc, c_src, d), ep_axis, 0, 0,
-                tiled=False)  # (n_ep, e_loc, C_src, d): sources stacked
-            xr = jnp.moveaxis(xr, 0, 1).reshape(e_loc, n_ep * c_src, d)
+            xe, buf_tok, buf_gate = self._dispatch_local(x2d, gates, ids,
+                                                         c_src)
+            with jax.named_scope("moe/dispatch"):
+                # ship capacity buffers to expert owners: E = n_ep * e_loc
+                xr = jax.lax.all_to_all(
+                    xe.reshape(n_ep, e_loc, c_src, d), ep_axis, 0, 0,
+                    tiled=False)  # (n_ep, e_loc, C_src, d): sources stacked
+                xr = jnp.moveaxis(xr, 0, 1).reshape(e_loc, n_ep * c_src, d)
             ye = self._expert_ffn(up, gate, down, xr, scales=scales)
-            ye = jnp.moveaxis(ye.reshape(e_loc, n_ep, c_src, d), 1, 0)
-            yb = jax.lax.all_to_all(ye, ep_axis, 0, 0, tiled=False)
-            yb = yb.reshape(E, c_src, d)  # back at the source, per expert
+            with jax.named_scope("moe/combine"):
+                ye = jnp.moveaxis(ye.reshape(e_loc, n_ep, c_src, d), 1, 0)
+                yb = jax.lax.all_to_all(ye, ep_axis, 0, 0, tiled=False)
+                yb = yb.reshape(E, c_src, d)  # back at the source
             y = self._combine_local(yb, buf_tok, buf_gate, t_loc)
             aux = {n: jax.lax.pmean(v, all_axes) for n, v in aux.items()}
             return y.reshape(b, s, d), aux

@@ -495,6 +495,7 @@ class LM:
             x = x * jnp.asarray(np.sqrt(cfg.d_model), cdt)
         return shard(x, "batch", "seq", None)
 
+    @jax.named_scope("lm_head")
     def logits_fn(self, params: dict, h: jax.Array) -> jax.Array:
         cfg = self.cfg
         if self.head is not None:
@@ -522,12 +523,16 @@ class LM:
 
     def loss(self, params: dict, batch: dict) -> Tuple[jax.Array, dict]:
         """Next-token cross entropy, chunked over the sequence."""
-        cfg = self.cfg
         h, _, aux = self.forward(params, batch)
+        return self._loss(params, h, batch["labels"], aux)
+
+    @jax.named_scope("loss")
+    def _loss(self, params: dict, h: jax.Array, labels: jax.Array,
+              aux: dict) -> Tuple[jax.Array, dict]:
+        cfg = self.cfg
         # gather the (seq-sharded) hidden once, in bf16, before chunking —
         # otherwise every chunk's dynamic_slice re-gathers it
         h = shard(h, "batch", None, None)
-        labels = batch["labels"]
         b, s = labels.shape
         chunk = min(cfg.loss_chunk, s)
         n_chunks = s // chunk

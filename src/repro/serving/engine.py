@@ -60,6 +60,13 @@ from .scheduler import Request, Scheduler, StepPlan
 from .spec import PromptLookupDrafter
 
 
+@jax.jit
+def sample_greedy(logits: jax.Array, row=None) -> jax.Array:
+    """Greedy tokens of a step's logits (B, C, V): the argmax over the
+    vocabulary at each position of every row, (B, C), or of ``row``, (C,)."""
+    return jnp.argmax(logits if row is None else logits[row], axis=-1)
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Engine knobs. ``token_budget`` is the per-step work quantum (the
@@ -175,11 +182,6 @@ class ServingEngine:
         self._m_itl = self.obs.histogram(
             "serving_itl_seconds",
             "inter-token latency per slot (consecutive emitted tokens)")
-        self._m_step = self.obs.histogram(
-            "serving_step_seconds", "engine step wall-clock duration")
-        self._m_tps = self.obs.gauge(
-            "serving_tokens_per_s",
-            "instantaneous step throughput (plan tokens / step seconds)")
         self._m_queue = self.obs.gauge(
             "serving_queue_depth", "requests waiting for admission")
         self._m_slots = self.obs.gauge(
@@ -335,8 +337,10 @@ class ServingEngine:
     # -- sampling ----------------------------------------------------------
 
     def _sample(self, logits: jax.Array, slot: int) -> int:
+        """The token after ``slot``'s row of a step's logits (B, 1, V)."""
         if self.config.greedy:
-            return int(jnp.argmax(logits))
+            return int(sample_greedy(logits, slot)[0])
+        logits = logits[slot, 0]
         seq = self.sched.active[slot]
         # per-request stream, folded by absolute position: a preempted and
         # recomputed sequence re-draws identical tokens
@@ -365,39 +369,41 @@ class ServingEngine:
 
     def step(self) -> Tuple[StepPlan, List[Tuple[int, np.ndarray]]]:
         """Run one engine step; returns (plan, finished) where finished is
-        a list of (req_id, generated token ids)."""
-        t0 = time.perf_counter()
-        with self._in_ctx(), obs_trace.span("engine/step",
-                                            registry=self.obs):
-            plan, finished = self._step_impl()
-        dt = time.perf_counter() - t0
-        self._m_step.observe(dt)
-        if plan.n_tokens and dt > 0:
-            self._m_tps.set(plan.n_tokens / dt)
-        self._m_queue.set(len(self.sched.waiting))
-        self._m_slots.set(sum(s is not None for s in self.sched.active))
-        total = self.config.total_pages
-        used = total - self.sched._free
-        self._m_occ.set(used / total)
-        self._m_pages_hw.set_max(used)
-        return plan, finished
+        a list of (req_id, generated token ids).
+
+        The host phases of a step are spans inside ``engine/step``:
+        ``engine/schedule`` (the plan, slot resets); per prefill group
+        ``engine/prefill`` (input assembly, copies, the call) then
+        ``engine/commit`` (per-slot bookkeeping, where the sample of a
+        prompt that ends is an ``engine/sync``); ``engine/decode`` or
+        ``engine/verify``, then ``engine/sync`` (the argmax pull) and
+        ``engine/commit``; ``engine/finish`` (finished requests, gauges).
+        """
+        with self._in_ctx(), self._span("engine/step"):
+            return self._step_impl()
+
+    def _span(self, name: str, **attrs):
+        return obs_trace.span(name, registry=self.obs, **attrs)
 
     def _step_impl(self) -> Tuple[StepPlan, List[Tuple[int, np.ndarray]]]:
         cfg = self.config
-        plan = self.sched.schedule()
-
-        # a re-admitted slot may have hosted another sequence: clear its
-        # recurrent (SSM) state before the first prefill chunk touches it
-        for slot in plan.admitted:
-            self.cache = self.model.stack.reset_slot_state(self.cache,
-                                                           slot)
-            self._last_tok[slot] = None
-
         slots = cfg.max_slots
-        if plan.prefill_groups:
-            n_pf = sum(len(toks) for group in plan.prefill_groups
-                       for _, _, toks in group)
-            self._m_tok.inc(n_pf, phase="prefill")
+        with self._span("engine/schedule"):
+            plan = self.sched.schedule()
+            # a re-admitted slot may have hosted another sequence: clear
+            # its recurrent (SSM) state before the first prefill chunk
+            # touches it
+            for slot in plan.admitted:
+                self.cache = self.model.stack.reset_slot_state(self.cache,
+                                                               slot)
+                self._last_tok[slot] = None
+            if plan.prefill_groups:
+                n_pf = sum(len(toks) for group in plan.prefill_groups
+                           for _, _, toks in group)
+                self._m_tok.inc(n_pf, phase="prefill")
+            if plan.decode_slots:
+                self._m_tok.inc(len(plan.decode_slots), phase="decode")
+
         for group in plan.prefill_groups:
             # equal-length chunks from different sequences packed into
             # ONE batched call (rows are slot-indexed; slots without a
@@ -405,71 +411,78 @@ class ServingEngine:
             # there are O(log prefill_chunk) compiled shapes, not
             # O(slots) sequential launches)
             c = len(group[0][2])
-            tokens = np.zeros((slots, c), np.int32)
-            pos = np.zeros((slots,), np.int32)
-            n_new = np.zeros((slots,), np.int32)
-            for slot, start, toks in group:
-                tokens[slot, :len(toks)] = toks
-                pos[slot] = start
-                n_new[slot] = len(toks)
-            with obs_trace.span("engine/prefill", registry=self.obs,
-                                chunk=c, rows=len(group)):
+            with self._span("engine/prefill", chunk=c, rows=len(group)):
+                tokens = np.zeros((slots, c), np.int32)
+                pos = np.zeros((slots,), np.int32)
+                n_new = np.zeros((slots,), np.int32)
+                for slot, start, toks in group:
+                    tokens[slot, :len(toks)] = toks
+                    pos[slot] = start
+                    n_new[slot] = len(toks)
                 logits, self.cache = self._step(
                     self.params, self.cache, self.sched.state.page_table,
                     jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(n_new),
                     jnp.arange(slots, dtype=jnp.int32))
-            for slot, start, toks in group:
-                self.sched.advance_prefill(slot, len(toks))
-                seq = self.sched.active[slot]
-                if not seq.prefilling \
-                        and len(seq.tokens) == seq.n_prefilled:
-                    # prompt fully cached and no pending token yet (also
-                    # true right after a preemption recompute): sample it
-                    self.sched.append_token(
-                        slot, self._sample(logits[slot, 0], slot))
-                    self._emit(slot)
+            with self._span("engine/commit"):
+                for slot, start, toks in group:
+                    self.sched.advance_prefill(slot, len(toks))
+                    seq = self.sched.active[slot]
+                    if not seq.prefilling \
+                            and len(seq.tokens) == seq.n_prefilled:
+                        # prompt fully cached and no pending token yet
+                        # (also true right after a preemption recompute):
+                        # sample it
+                        with self._span("engine/sync"):
+                            tok = self._sample(logits, slot)
+                        self.sched.append_token(slot, tok)
+                        self._emit(slot)
 
         kmax = max((len(plan.drafts.get(s, ()))
                     for s in plan.decode_slots), default=0)
-        if plan.decode_slots:
-            self._m_tok.inc(len(plan.decode_slots), phase="decode")
         if plan.decode_slots and kmax == 0:
             # plain decode (C == 1): the PR-3 baseline path, bit-for-bit
-            tokens = np.zeros((slots, 1), np.int32)
-            n_new = np.zeros((slots,), np.int32)
-            for s in plan.decode_slots:
-                tokens[s, 0] = self.sched.active[s].pending_token
-                n_new[s] = 1
-            with obs_trace.span("engine/decode", registry=self.obs,
-                                rows=len(plan.decode_slots)):
+            with self._span("engine/decode", rows=len(plan.decode_slots)):
+                tokens = np.zeros((slots, 1), np.int32)
+                n_new = np.zeros((slots,), np.int32)
+                for s in plan.decode_slots:
+                    tokens[s, 0] = self.sched.active[s].pending_token
+                    n_new[s] = 1
                 logits, self.cache = self._step(
                     self.params, self.cache, self.sched.state.page_table,
                     jnp.asarray(tokens), self.sched.state.seq_lens,
                     jnp.asarray(n_new),
                     jnp.arange(slots, dtype=jnp.int32))
-            greedy_toks = np.asarray(
-                jnp.argmax(logits[:, 0, :], axis=-1)) \
-                if cfg.greedy else None
-            for s in plan.decode_slots:
-                self.sched.note_decoded(s)
-                tok = int(greedy_toks[s]) if cfg.greedy \
-                    else self._sample(logits[s, 0], s)
-                self.sched.append_token(s, tok)
-                self._emit(s)
+            if cfg.greedy:
+                with self._span("engine/sync"):
+                    greedy_toks = np.asarray(sample_greedy(logits))[:, 0]
+            with self._span("engine/commit"):
+                for s in plan.decode_slots:
+                    self.sched.note_decoded(s)
+                    tok = int(greedy_toks[s]) if cfg.greedy \
+                        else self._sample(logits, s)
+                    self.sched.append_token(s, tok)
+                    self._emit(s)
         elif plan.decode_slots:
             self._verify_decode(plan)
 
-        finished = []
-        for s in range(cfg.max_slots):
-            seq = self.sched.active[s]
-            if seq is not None and seq.done:
-                req, gen = self.sched.finish(s)
-                self.outputs[req.req_id] = gen
-                self._t_added.pop(req.req_id, None)
-                self._last_tok[s] = None
-                self._m_req.inc(event="finished")
-                finished.append((req.req_id, gen))
+        with self._span("engine/finish"):
+            finished = []
+            for s in range(cfg.max_slots):
+                seq = self.sched.active[s]
+                if seq is not None and seq.done:
+                    req, gen = self.sched.finish(s)
+                    self.outputs[req.req_id] = gen
+                    self._t_added.pop(req.req_id, None)
+                    self._last_tok[s] = None
+                    self._m_req.inc(event="finished")
+                    finished.append((req.req_id, gen))
+            self._m_queue.set(len(self.sched.waiting))
+            self._m_slots.set(sum(s is not None for s in self.sched.active))
+            total = cfg.total_pages
+            used = total - self.sched._free
+            self._m_occ.set(used / total)
+            self._m_pages_hw.set_max(used)
         return plan, finished
 
     def _verify_decode(self, plan: StepPlan) -> None:
@@ -483,44 +496,46 @@ class ServingEngine:
         ``kv_cache.truncate``."""
         slots = self.config.max_slots
         c = 1 + self.spec_k
-        tokens = np.zeros((slots, c), np.int32)
-        n_new = np.zeros((slots,), np.int32)
-        n_prop = 0
-        for s in plan.decode_slots:
-            row = [self.sched.active[s].pending_token] \
-                + plan.drafts.get(s, [])
-            tokens[s, :len(row)] = row
-            n_new[s] = len(row)
-            n_prop += len(row) - 1
-        if n_prop:
-            self._m_spec.inc(n_prop, result="proposed")
-            self._m_tok.inc(n_prop, phase="spec_draft")
-        with obs_trace.span("engine/verify", registry=self.obs,
-                            rows=len(plan.decode_slots), chunk=c):
+        with self._span("engine/verify", rows=len(plan.decode_slots),
+                        chunk=c):
+            tokens = np.zeros((slots, c), np.int32)
+            n_new = np.zeros((slots,), np.int32)
+            n_prop = 0
+            for s in plan.decode_slots:
+                row = [self.sched.active[s].pending_token] \
+                    + plan.drafts.get(s, [])
+                tokens[s, :len(row)] = row
+                n_new[s] = len(row)
+                n_prop += len(row) - 1
+            if n_prop:
+                self._m_spec.inc(n_prop, result="proposed")
+                self._m_tok.inc(n_prop, phase="spec_draft")
             logits, self.cache = self._verify(
                 self.params, self.cache, self.sched.state.page_table,
                 jnp.asarray(tokens), self.sched.state.seq_lens,
                 jnp.asarray(n_new), jnp.arange(slots, dtype=jnp.int32))
-        greedy = np.asarray(jnp.argmax(logits, axis=-1))    # (slots, C)
-        for s in plan.decode_slots:
-            drafts = plan.drafts.get(s, [])
-            g = greedy[s]
-            m = 0
-            while m < len(drafts) and drafts[m] == int(g[m]):
-                m += 1
-            if m:
-                self._m_spec.inc(m, result="accepted")
-            if len(drafts) - m:
-                self._m_spec.inc(len(drafts) - m, result="rolled_back")
-            # committed: the pending token + m accepted drafts; emitted:
-            # their greedy continuations g[0..m] (g[m] is the bonus token
-            # from the last accepted position — it becomes the new
-            # pending token, exactly as in plain decode)
-            self.sched.note_verified(s, n_written=1 + len(drafts),
-                                     n_accepted=1 + m)
-            for i in range(m + 1):
-                self.sched.append_token(s, int(g[i]))
-                self._emit(s)
+        with self._span("engine/sync"):
+            greedy = np.asarray(sample_greedy(logits))    # (slots, C)
+        with self._span("engine/commit"):
+            for s in plan.decode_slots:
+                drafts = plan.drafts.get(s, [])
+                g = greedy[s]
+                m = 0
+                while m < len(drafts) and drafts[m] == int(g[m]):
+                    m += 1
+                if m:
+                    self._m_spec.inc(m, result="accepted")
+                if len(drafts) - m:
+                    self._m_spec.inc(len(drafts) - m, result="rolled_back")
+                # committed: the pending token + m accepted drafts;
+                # emitted: their greedy continuations g[0..m] (g[m] is the
+                # bonus token from the last accepted position — it becomes
+                # the new pending token, exactly as in plain decode)
+                self.sched.note_verified(s, n_written=1 + len(drafts),
+                                         n_accepted=1 + m)
+                for i in range(m + 1):
+                    self.sched.append_token(s, int(g[i]))
+                    self._emit(s)
 
     # -- drain loop --------------------------------------------------------
 

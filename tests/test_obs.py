@@ -14,10 +14,13 @@ Layers:
 * **purity**     — the engine's jitted paged step and the trainer's step
   lower to byte-identical HLO with metrics on vs off, and sparselint's
   SL201 pass finds no host-sync primitive in either;
-* **surfaces**   — the ``/metrics`` HTTP endpoint and the dump CLI.
+* **surfaces**   — the ``/metrics`` HTTP endpoint and the dump CLI;
+* **names**      — the model's scopes reach the lowered programs' op_name
+  metadata, and an engine step records its documented host spans.
 """
 import json
 import os
+import re
 import urllib.request
 
 import jax
@@ -376,3 +379,122 @@ def test_timed_call_reads_registry_spans():
     assert len(reg.span_durations("bench/mul")) == 3
     cnt, _ = reg.histogram("repro_span_seconds").stats(span="bench/mul")
     assert cnt == 3
+
+
+# ---------------------------------------------------------------------------
+# names: model scopes in the op_name metadata, engine spans per step
+# ---------------------------------------------------------------------------
+
+SCOPES = ("attn/proj", "attn/core", "attn/decode", "ffn", "moe/router",
+          "moe/dispatch", "moe/experts", "moe/combine", "lm_head", "loss")
+# the substrings by which the benchmark's trace reduction puts a device op
+# into a kernel family (``FAMILIES`` in bench/trace_reduce.py): no scope
+# may hold one, or XLA ops under it would count as kernel time
+FAMILY_KEYS = ("_fwd_kernel", "_dx_kernel", "_dw_kernel", "csd_spmm",
+               "_paged_decode", "paged_decode", "pallas_call",
+               "tpu_custom_call")
+ENGINE_SPANS = {"engine/step", "engine/schedule", "engine/prefill",
+                "engine/decode", "engine/verify", "engine/sync",
+                "engine/commit", "engine/finish"}
+
+
+def _scopes_in(lowered) -> set:
+    """The scopes that appear as path segments of the op_name locations
+    of a lowered program, through transform wrappers such as
+    ``transpose(jvp(moe/dispatch))``."""
+    found = set()
+    for name in re.findall(r'loc\("([^"]+)"',
+                           lowered.as_text(debug_info=True)):
+        path = "/" + re.sub(r"[\w.-]+\(|\)", "", name) + "/"
+        found.update(sc for sc in SCOPES if f"/{sc}/" in path)
+    return found
+
+
+def test_scope_names_hold_no_kernel_family_key():
+    assert not [(sc, k) for sc in SCOPES for k in FAMILY_KEYS if k in sc]
+
+
+def test_model_scopes_reach_op_names():
+    """A tiny MoE training step and a tiny dense paged step (prefill
+    chunk and decode) name every scope of the model's layers."""
+    from repro.nn import MoEConfig, ModelConfig, build_model
+    from repro.nn.common import dtype_of
+    from repro.optim import adam
+    from repro.serving import EngineConfig, ServingEngine
+    from repro.train import Trainer, TrainerConfig
+
+    moe = build_model(ModelConfig(
+        n_layers=2, d_model=32, n_heads=2, n_kv_heads=2, d_ff=64,
+        vocab_size=64, attn_chunk=16, loss_chunk=16, dtype="float32",
+        remat=False, moe=MoEConfig(n_routed=4, top_k=2, d_expert=16)))
+    tr = Trainer(moe, TrainerConfig(), registry=Registry())
+    batch = {"tokens": np.zeros((2, 16), np.int32),
+             "labels": np.zeros((2, 16), np.int32)}
+    p_avals = jax.eval_shape(moe.init, jax.random.key(0))
+    train = _scopes_in(tr._make_step(batch).lower(
+        p_avals, jax.eval_shape(adam.init, p_avals),
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                     batch)))
+    assert train >= {"attn/proj", "attn/core", "moe/router", "moe/dispatch",
+                     "moe/experts", "moe/combine", "lm_head", "loss"}
+
+    model = _tiny_model()
+    eng = ServingEngine(
+        model, model.init(jax.random.key(0)),
+        EngineConfig(max_slots=2, page_size=8, total_pages=16,
+                     max_pages_per_seq=4, token_budget=8, prefill_chunk=8),
+        registry=Registry())
+    i32 = np.int32
+    cache = jax.eval_shape(lambda: model.stack.init_paged_cache(
+        2, 16, 8, dtype_of(model.cfg)))
+    p_avals = jax.eval_shape(model.init, jax.random.key(0))
+    serve = set()
+    for chunk in (1, 4):
+        serve |= _scopes_in(eng._step.lower(
+            p_avals, cache, jax.ShapeDtypeStruct((2, 4), i32),
+            jax.ShapeDtypeStruct((2, chunk), i32),
+            *[jax.ShapeDtypeStruct((2,), i32)] * 3))
+    assert serve >= {"attn/proj", "attn/core", "attn/decode", "ffn",
+                     "lm_head"}
+    assert train | serve == set(SCOPES)
+
+
+def _span_counts(reg) -> dict:
+    h = reg.histogram("repro_span_seconds")
+    return {dict(k)["span"]: s.count for k, s in h.series.items()}
+
+
+def test_engine_step_records_its_host_phases():
+    """One prefill step (three prompts end in one group) and one decode
+    step record the documented spans, each once per step or per call,
+    except ``engine/sync``, once per prompt that ends."""
+    from repro.serving import EngineConfig, ServingEngine
+
+    model = _tiny_model()
+    reg = Registry()
+    eng = ServingEngine(
+        model, model.init(jax.random.key(0)),
+        EngineConfig(max_slots=4, page_size=8, total_pages=16,
+                     max_pages_per_seq=4, token_budget=32,
+                     prefill_chunk=8, backend="xla"), registry=reg)
+    for i in range(3):
+        eng.add_request(np.full(8, i + 1, np.int32), 4)
+    before = _span_counts(reg)
+    plan, _ = eng.step()
+    assert len(plan.prefill_groups) == 1 and not plan.decode_slots
+    prefill = _span_counts(reg)
+    plan, _ = eng.step()
+    assert len(plan.decode_slots) == 3
+    decode = _span_counts(reg)
+    assert set(decode) <= ENGINE_SPANS
+    once = {"engine/step": 1, "engine/schedule": 1, "engine/commit": 1,
+            "engine/finish": 1}
+
+    def diff(after, prev):
+        return {k: v - prev.get(k, 0) for k, v in after.items()
+                if v > prev.get(k, 0)}
+
+    assert diff(prefill, before) == dict(
+        once, **{"engine/prefill": 1, "engine/sync": 3})
+    assert diff(decode, prefill) == dict(
+        once, **{"engine/decode": 1, "engine/sync": 1})

@@ -1035,7 +1035,8 @@ def test_engine_obs_counters_consistent():
     assert drafted == eng.sched.stats["spec_drafted"]
     assert 0.0 <= reg.gauge("serving_page_occupancy").value() <= 1.0
     assert reg.gauge("serving_pages_highwater").value() > 0
-    scnt, ssum = reg.histogram("serving_step_seconds").stats()
+    scnt, ssum = reg.histogram("repro_span_seconds").stats(
+        span="engine/step")
     assert scnt == eng.sched.stats["steps"] and ssum > 0
 
 

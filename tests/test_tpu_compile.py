@@ -10,7 +10,10 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process may load the TPU library, and with several test
 workers every worker imports this file.
 """
+import collections
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +24,12 @@ from repro.configs import get_config
 from repro.core.block_pattern import fit_block_pattern
 from repro.kernels import csd_spmm, ops
 from repro.kernels.flash_attention import paged_decode_attention
+from repro.kernels.names import KERNELS
 
+# the substrings by which the benchmark's trace reduction puts a device op
+# into a kernel family (``FAMILIES`` in bench/trace_reduce.py)
+FAMILY_KEYS = ("_fwd_kernel", "_dx_kernel", "_dw_kernel", "csd_spmm",
+               "_paged_decode", "paged_decode")
 QWEN = get_config("qwen2_7b")
 TOKENS = 256          # two 128-row tiles
 BF16 = jnp.bfloat16
@@ -68,10 +76,43 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _instructions(text):
+    """The compiled program's instructions, one string each (an
+    instruction's kernel metadata spans lines)."""
+    out, open_ = [], False
+    for line in text.splitlines():
+        if re.match(r"\s*(ROOT )?%", line):
+            out.append(line)
+            open_ = True
+        elif open_ and line.strip():
+            out[-1] += line
+        else:
+            open_ = False
+    return out
+
+
+def _compile(fn, *args, derived=("get-tuple-element", "copy")):
+    """Compile for the described chip; count the kernel names of its
+    Pallas calls, each checked against its instruction's name. A trace
+    prints each op with its operands' instruction names, so a kernel name
+    may appear only in the kernel's own instruction, and in the
+    instructions XLA ``derived`` from it (the tuple reads of its outputs,
+    which do not run, and a copy that changes an output's layout): else
+    ops that consume its output would be counted as the kernel."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
-    return text
+    kernels = collections.Counter()
+    for ins in _instructions(text):
+        if 'custom_call_target="tpu_custom_call"' in ins:
+            m = re.search(r'%([\w.-]+) = .*kernel_metadata=\{\s*'
+                          r'"kernel":"(\w+)"', ins)
+            assert m, ins[:200]
+            # under a transform the name carries it: transpose_jvp_..._
+            assert KERNELS[m.group(2)] in m.group(1), ins[:200]
+            kernels[m.group(2)] += 1
+        elif not [d for d in derived if f" {d}(" in ins]:
+            assert not [k for k in FAMILY_KEYS if k in ins], ins[:200]
+    return kernels
 
 
 @pytest.mark.parametrize("junction", ["up", "down"])
@@ -84,13 +125,15 @@ def test_fwd_with_bias_compiles(one_chip, junction, quant):
     if quant:
         w = _spec(w_shape, jnp.int8, one_chip)
         s = _spec(w_shape[:2], jnp.float32, one_chip)
-        _compile(lambda x, w, s, b: csd_spmm.csd_spmm_fwd(
+        kernels = _compile(lambda x, w, s, b: csd_spmm.csd_spmm_fwd(
             x, w, bp.block_idx, bias=b, activation="relu", w_scale=s),
             x, w, s, b)
+        assert set(kernels) == {"csd_spmm_fwd_int8"}
     else:
         w = _spec(w_shape, BF16, one_chip)
-        _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
+        kernels = _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
             x, w, bp.block_idx, bias=b, activation="relu"), x, w, b)
+        assert set(kernels) == {"csd_spmm_fwd"}
 
 
 @pytest.mark.parametrize("activation", ["relu", "gelu"])
@@ -106,9 +149,9 @@ def test_fwd_dx_dw_gradient_compiles(one_chip, activation):
                            backend="pallas")
         return jnp.sum(y.astype(jnp.float32))
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b)
+    kernels = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b)
     # forward, dx and dw each lower to their own kernel
-    assert text.count("tpu_custom_call") >= 3
+    assert set(kernels) == {"csd_spmm_fwd", "csd_spmm_dx", "csd_spmm_dw"}
 
 
 def test_batched_fwd_and_gradient_compile(one_chip):
@@ -118,15 +161,18 @@ def test_batched_fwd_and_gradient_compile(one_chip):
     w = _spec((experts, bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out),
               BF16, one_chip)
     b = _spec((experts, bp.n_out), BF16, one_chip)
-    _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
-        x, w, bp.block_idx, bias=b, activation="relu"), x, w, b)
+    assert _compile(lambda x, w, b: csd_spmm.csd_spmm_fwd(
+        x, w, bp.block_idx, bias=b, activation="relu"), x, w, b).keys() \
+        == {"csd_spmm_fwd_batched"}
 
     def loss(x, w, b):
         y = ops.csd_matmul(x, w, bp, bias=b, activation="gelu",
                            backend="pallas")
         return jnp.sum(y.astype(jnp.float32))
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b)
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b).keys() == {
+        "csd_spmm_fwd_batched", "csd_spmm_dx_batched",
+        "csd_spmm_dw_batched"}
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -141,12 +187,51 @@ def test_paged_decode_compiles(one_chip, quant):
     ln = _spec((slots,), jnp.int32, one_chip)
     if quant:
         sc = _spec((pool, page_size), jnp.float32, one_chip)
-        _compile(lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
-            q, k, v, pt, ln, backend="pallas", k_scale=ks, v_scale=vs),
+        kernels = _compile(
+            lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
+                q, k, v, pt, ln, backend="pallas", k_scale=ks, v_scale=vs),
             q, kv, kv, pt, ln, sc, sc)
     else:
-        _compile(lambda q, k, v, pt, ln: paged_decode_attention(
+        kernels = _compile(lambda q, k, v, pt, ln: paged_decode_attention(
             q, k, v, pt, ln, backend="pallas"), q, kv, kv, pt, ln)
+    assert set(kernels) == {"paged_decode_attention"}
+
+
+@pytest.mark.parametrize("chunk", [1, 64], ids=["decode", "prefill"])
+def test_paged_step_names_its_kernels(one_chip, chunk):
+    """The engine's paged step at qwen2_7b widths (two layers, 8 slots):
+    its layer body runs the three FFN junctions (up, gate, down) as
+    ``csd_spmm_fwd`` and, in decode, the paged attention; every other op
+    (the dense attention projections and head among them) is XLA's and
+    carries no kernel name, not even the ops that read a kernel's output
+    (strict: no derived instruction is let through either)."""
+    from repro.nn import build_model
+    from repro.nn.common import dtype_of
+
+    cfg = dataclasses.replace(
+        QWEN, n_layers=2,
+        sparsity=dataclasses.replace(QWEN.sparsity, backend="pallas"))
+    model = build_model(cfg)
+    slots, pages = 8, 64
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+
+    params = shapes(jax.eval_shape(model.init, jax.random.key(0)))
+    cache = shapes(jax.eval_shape(lambda: model.stack.init_paged_cache(
+        slots, pages, 16, dtype_of(cfg))))
+    i32 = jnp.int32
+    kernels = _compile(
+        lambda p, c, pt, t, pos, n, s: model.paged_step(
+            p, t, pos, n, c, pt, s, backend="pallas"),
+        params, cache, _spec((slots, 16), i32, one_chip),
+        _spec((slots, chunk), i32, one_chip),
+        *[_spec((slots,), i32, one_chip)] * 3, derived=())
+    want = {"csd_spmm_fwd": 3}
+    if chunk == 1:
+        want["paged_decode_attention"] = 1
+    assert kernels == want
 
 
 def test_junction_widths_are_published():
