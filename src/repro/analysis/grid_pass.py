@@ -37,12 +37,12 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..kernels.vmem import VMEM_BUDGET
 from .capture import CapturedLaunch, capture_launch
 from .findings import Finding
 
-# Default per-core VMEM budget for SL104: TPU cores carry ~16 MiB of VMEM;
-# Mosaic needs headroom for semaphores/metadata, so certify against half.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+# Default per-core VMEM budget for SL104: the one the kernels tile to.
+DEFAULT_VMEM_BUDGET = VMEM_BUDGET
 
 
 @dataclasses.dataclass
@@ -228,23 +228,33 @@ def _shard_pattern():
 
 
 def _fwd_case(batched: bool, activation: Optional[str], name: str,
-              save_preact: bool = False) -> KernelCase:
+              save_preact: bool = False, pattern=None, m: int = 256,
+              dtype: str = "float32", block_m: Optional[int] = 128,
+              fan_in_block: Optional[int] = 2) -> KernelCase:
+    """A forward launch; the fan-in chunk axis (3) fires the epilogue.
+    By default two row blocks of a fan-in of 4 in chunks of 2 slots, so
+    each output tile is visited twice; ``block_m`` and ``fan_in_block``
+    None derive the tiling as production does (``csd_spmm.fwd_tiling``)."""
     def build():
         import jax.numpy as jnp
         from ..kernels import csd_spmm
-        bp = _demo_pattern()
-        m, bm = 256, 128
-        x = jnp.zeros(((2,) if batched else ()) + (m, bp.n_in), jnp.float32)
+        bp = pattern() if pattern is not None else _demo_pattern(n_lb=8)
+        lead = (2,) if batched else ()
+        x = jnp.zeros(lead + (m, bp.n_in), dtype)
         w = jnp.zeros(
-            ((2,) if batched else ())
-            + (bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out), jnp.float32)
-        bias = jnp.zeros(((2,) if batched else ()) + (bp.n_out,),
-                         jnp.float32)
+            lead + (bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out), dtype)
+        bias = jnp.zeros(lead + (bp.n_out,), dtype)
         return capture_launch(
             csd_spmm.csd_spmm_fwd, x, w, bp.block_idx, bias=bias,
-            activation=activation, save_preact=save_preact, block_m=bm,
-            name=name)
-    return KernelCase(name, build, epilogue_axis=3 if batched else 2)
+            activation=activation, save_preact=save_preact,
+            block_m=block_m, fan_in_block=fan_in_block, name=name)
+    return KernelCase(name, build, epilogue_axis=3)
+
+
+def _qwen_down_pattern():
+    """qwen2_7b's down junction's fan-in (d_in_b 111: 148 left blocks at
+    rho 0.75) over four right blocks: the widest forward step."""
+    return _demo_pattern(n_lb=148, n_rb=4, rho=0.75)
 
 
 def _dx_case(batched: bool, name: str, shard_local: bool = False
@@ -322,6 +332,15 @@ def kernel_cases() -> List[KernelCase]:
                   save_preact=True),
         _fwd_case(False, None, "csd_spmm_fwd_4d_plain"),
         _fwd_case(True, "relu", "csd_spmm_fwd_5d_batched"),
+        # qwen2_7b down's fan-in in bf16 at the derived tiling: decode's
+        # rows take the whole fan-in in one step (the widest step),
+        # prefill's three chunks
+        _fwd_case(False, None, "csd_spmm_fwd_4d_fanin111_decode",
+                  pattern=_qwen_down_pattern, m=16, dtype="bfloat16",
+                  block_m=None, fan_in_block=None),
+        _fwd_case(False, None, "csd_spmm_fwd_4d_fanin111_prefill",
+                  pattern=_qwen_down_pattern, m=512, dtype="bfloat16",
+                  block_m=None, fan_in_block=None),
         _dx_case(False, "csd_spmm_dx_4d"),
         _dx_case(False, "csd_spmm_dx_4d_shardlocal", shard_local=True),
         _dx_case(True, "csd_spmm_dx_5d_batched"),
@@ -350,27 +369,31 @@ def _aliased_fwd_copy(x, w, block_idx, *, block_m=128):
     from ..kernels.csd_spmm import _fwd_kernel
     m, n_in = x.shape
     n_rb, d_in_b, bl, br = w.shape
-    grid = (d_in_b, m // block_m, n_rb)  # BUG: fan-in slot outermost
-    kernel = functools.partial(_fwd_kernel, d_in_b=d_in_b, activation=None,
-                               has_bias=False, save_preact=False)
+    k = 1  # one fan-in slot a chunk
+    grid = (d_in_b, 1, m // block_m, n_rb)  # BUG: fan-in chunk outermost
+    kernel = functools.partial(_fwd_kernel, k=k, d_in_b=d_in_b, quant=False,
+                               activation=None, has_bias=False,
+                               save_preact=False)
+    x_specs = [pl.BlockSpec((1, block_m, bl),
+                            lambda f, e, i, r, idx, j=j:
+                            (e, i, idx[r, f * k + j]))
+               for j in range(k)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, bl),
-                             lambda f, i, r, idx: (i, idx[r, f])),
-                pl.BlockSpec((1, 1, bl, br),
-                             lambda f, i, r, idx: (r, f, 0, 0)),
+            in_specs=x_specs + [
+                pl.BlockSpec((1, 1, k, bl, br),
+                             lambda f, e, i, r, idx: (e, r, f, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((block_m, br),
-                                   lambda f, i, r, idx: (i, r)),
+            out_specs=pl.BlockSpec((1, block_m, br),
+                                   lambda f, e, i, r, idx: (e, i, r)),
         ),
-        out_shape=jax.ShapeDtypeStruct((m, n_rb * br), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, m, n_rb * br), jnp.float32),
         interpret=True,
-    )(jnp.asarray(block_idx), x, w)
-    return out
+    )(jnp.asarray(block_idx), *[x[None]] * k, w[None])
+    return out[0]
 
 
 def injected_alias_case() -> KernelCase:

@@ -70,9 +70,10 @@ def _audit_junction(key: str, parsed: dict, ent: dict) -> List[Finding]:
         bp = make_block_pattern(parsed["n_in"], parsed["n_out"],
                                 bp_rho_cap(parsed["rho"]), block_in=bi,
                                 block_out=bo, seed=0)
-        ok, fs = certify.certify_junction(bp, parsed["m"],
-                                          int(ent.get("block_m", 128)),
-                                          E=parsed["E"])
+        bm = ent.get("block_m")
+        ok, fs = certify.certify_junction(
+            bp, parsed["m"], None if bm is None else int(bm),
+            E=parsed["E"])
     except Exception as e:
         return [Finding("SL401", key,
                         f"cached pallas entry cannot be re-certified: "

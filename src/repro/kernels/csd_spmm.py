@@ -13,12 +13,13 @@ Mapping of the paper's architecture onto the TPU grid:
   *scalar-prefetched* pattern ``block_idx`` (the interleaved-order access of
   Fig. 2(b): the index map plays the role of the address generator built
   from the seed vector ``phi``);
-* clash-freedom                       -> each grid step streams exactly one
-  left block from HBM; a left block is never double-streamed within a step,
-  and consecutive ``f`` steps revisit the same *output* tile so the partial
-  sum stays resident in VMEM (the "natural order" write of Fig. 2(b));
+* clash-freedom                       -> each grid step streams one left
+  block per fan-in slot it reduces (the forward folds ``k`` slots into a
+  step, the backward kernels one); consecutive ``f`` steps revisit the
+  same *output* tile so the partial sum stays resident in VMEM (the
+  "natural order" write of Fig. 2(b));
 * the sigmoid/ReLU unit next to the edge processors -> the fused epilogue:
-  bias-add + activation are applied on the last fan-in slot while the
+  bias-add + activation are applied on the last fan-in chunk while the
   accumulator tile is still in VMEM, so the pre-activation never
   round-trips HBM (see ``csd_spmm_fwd(bias=..., activation=...)``).
 
@@ -34,8 +35,9 @@ becomes the *leading* (outermost, slowest-varying) grid dimension, so one
 ``BlockPattern`` is scalar-prefetched once and serves every expert — the
 paper's "not tied to a specific number of neurons" architecture replicated
 per expert with zero extra pattern memory. Inner grid order (row tile,
-right block, fan-in slot) is unchanged, so the per-expert schedule, VMEM
-residency, and clash-freedom argument are identical to the unbatched case.
+right block, fan-in slot or chunk) is unchanged, so the per-expert
+schedule, VMEM residency, and clash-freedom argument are identical to the
+unbatched case; the plain forward runs as the batched one with E = 1.
 
 All kernels are validated against ``ref.py`` in interpret mode (CPU) by
 ``tests/test_kernels.py``; ``tests/test_tpu_compile.py`` compiles them to
@@ -52,13 +54,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics as _obs_metrics
 from .names import pallas_names
+from .vmem import VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
 # Forward: y[m, rb] = sum_f x[m, block_idx[rb, f]] @ w[rb, f]
 #
-# Fused epilogue: on the LAST fan-in slot of each output tile the partial
+# Each grid step reduces a chunk of k fan-in slots: a grid step costs a
+# fixed few tenths of a microsecond, against 21 ns of MXU work for one
+# (128 x 128) slot, so the step count, not the work, set the time of the
+# one-slot-per-step schedule. ``fwd_tiling`` derives k and the row block
+# from the shapes and the VMEM budget.
+#
+# Fused epilogue: on the LAST fan-in chunk of each output tile the partial
 # sum is still resident in VMEM, so bias-add and the activation are applied
 # there — the pre-activation never round-trips HBM. This mirrors the FPGA
 # architecture (Dey et al. §III): the sigmoid/ReLU unit sits next to the
@@ -118,67 +128,116 @@ def _bias_tile(br: int) -> tuple:
     return (pl.squeezed, 1, br)
 
 
-def _fwd_kernel(idx_ref, *refs, d_in_b: int, activation: Optional[str],
-                has_bias: bool, save_preact: bool):
-    """refs: x, w, [bias], y, [preact] (inputs then outputs)."""
-    if has_bias:
-        x_ref, w_ref, b_ref = refs[:3]
-        out_refs = refs[3:]
+# Row blocks stop growing here: at 512 rows an x tile already holds four
+# times a weight tile's bytes, so re-reading the weights once per row block
+# costs at most a fifth of the call's bytes, and VMEM is left to fan-in.
+MAX_BLOCK_M = 512
+
+
+def sublane_rows(dtype) -> int:
+    """Rows of one TPU tile of ``dtype`` (8 for f32, 16 for bf16)."""
+    return max(8, 32 // np.dtype(dtype).itemsize)
+
+
+def fwd_rows(m: int, dtype) -> int:
+    """Rows a forward call of ``m`` rows is padded to when its row block is
+    derived: a sublane tile's multiple up to 128 rows (decode's handful of
+    rows stays a handful), else a multiple of 128 (the backward kernels'
+    row block)."""
+    if m <= 128:
+        return -(-m // sublane_rows(dtype)) * sublane_rows(dtype)
+    return -(-m // 128) * 128
+
+
+def _fwd_vmem(block_m: int, k: int, bl: int, br: int, x_bytes: int,
+              w_bytes: int, n_out: int, has_bias: bool) -> int:
+    """Per-step working set as SL104 counts it: every in/out block double
+    buffered: k x tiles, the (k, bL, bR) weight block, the f32 output
+    tile(s), the bias row."""
+    per = (k * (block_m * bl * x_bytes + bl * br * w_bytes)
+           + n_out * block_m * br * 4 + (br * 4 if has_bias else 0))
+    return 2 * per
+
+
+def fwd_tiling(m: int, d_in_b: int, bl: int, br: int, *, x_dtype, w_dtype,
+               n_out: int = 1, has_bias: bool = False,
+               block_m: Optional[int] = None,
+               fan_in_block: Optional[int] = None) -> tuple:
+    """``(block_m, k)`` of a forward call over ``m`` rows: the row block and
+    the fan-in slots each grid step reduces.
+
+    The step count per right block is ``(m / block_m) * ceil(d_in_b / k)``;
+    the pair with the fewest steps whose working set fits SL104's VMEM
+    budget wins, the larger row block on a tie. ``k`` divides ``d_in_b``
+    (every block lies inside the slab), so the whole fan-in where it fits.
+    Row blocks are sublane-tile multiples dividing ``m``, up to
+    ``MAX_BLOCK_M``. An explicit ``block_m`` (tests, the tune cache) is
+    taken as given, as is ``fan_in_block`` (tests), which must divide
+    ``d_in_b``; with no pair that fits, the smallest candidates.
+    """
+    if fan_in_block is not None and d_in_b % fan_in_block:
+        raise ValueError(f"fan_in_block={fan_in_block} does not divide "
+                         f"the fan-in d_in_b={d_in_b}")
+    s = sublane_rows(x_dtype)
+    if block_m is not None:
+        rows = [block_m]
     else:
-        x_ref, w_ref = refs[:2]
-        b_ref = None
-        out_refs = refs[2:]
+        rows = [b for b in range(s, min(m, MAX_BLOCK_M) + 1, s)
+                if m % b == 0] or [m]
+    if fan_in_block is not None:
+        slots = [fan_in_block]
+    else:
+        slots = [d for d in range(d_in_b, 0, -1) if d_in_b % d == 0]
+    xb, wb = np.dtype(x_dtype).itemsize, np.dtype(w_dtype).itemsize
+    best, best_key = (rows[0], slots[-1]), None
+    for bm in rows:
+        for k in slots:
+            if _fwd_vmem(bm, k, bl, br, xb, wb, n_out,
+                         has_bias) > VMEM_BUDGET:
+                continue
+            key = ((m // bm) * (d_in_b // k), -bm)
+            if best_key is None or key < best_key:
+                best, best_key = (bm, k), key
+            break  # slots run largest first: the first that fits is best
+    return best
+
+
+def _fwd_kernel(*refs, k: int, d_in_b: int, quant: bool,
+                activation: Optional[str], has_bias: bool,
+                save_preact: bool):
+    """One grid step ``(e, i, r, f)``: reduce fan-in slots ``f*k .. f*k+k-1``
+    of output tile ``(e, i, r)``.
+
+    refs: idx, [scale] (scalar prefetch), k x tiles, the (k, bL, bR)
+    weight block, [bias], y, [preact]; ``k`` divides ``d_in_b``."""
+    n_sp = 2 if quant else 1
+    scale_ref = refs[1] if quant else None
+    x_refs = refs[n_sp:n_sp + k]
+    w_ref = refs[n_sp + k]
+    rest = refs[n_sp + k + 1:]
+    b_ref = rest[0] if has_bias else None
+    out_refs = rest[1:] if has_bias else rest
     y_ref = out_refs[0]
-    f = pl.program_id(2)
+    e, r, f = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    n_f = d_in_b // k
 
     @pl.when(f == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    x = x_ref[...]  # (block_m, bL)
-    w = w_ref[0, 0]  # (bL, bR)
-    y_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=y_ref.dtype)
+    for j in range(k):
+        x = x_refs[j][0]  # (block_m, bL)
+        w = w_ref[0, 0, j]  # (bL, bR)
+        if quant:
+            w = w.astype(x.dtype)  # int8 -> compute dtype, in register
+        p = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                                preferred_element_type=y_ref.dtype)
+        if quant:
+            p = p * scale_ref[e, r, f * k + j]  # per-block scale, SMEM
+        y_ref[0] += p
 
     if has_bias or activation is not None or save_preact:
-        @pl.when(f == d_in_b - 1)
-        def _epilogue():
-            z = y_ref[...]
-            if has_bias:
-                z = z + b_ref[...].astype(z.dtype)  # (1, bR) broadcasts
-            if save_preact:
-                out_refs[1][...] = z
-            y_ref[...] = apply_activation(z, activation)
-
-
-def _fwd_kernel_batched(idx_ref, *refs, d_in_b: int,
-                        activation: Optional[str], has_bias: bool,
-                        save_preact: bool):
-    """Expert-major forward: same schedule as ``_fwd_kernel`` shifted one
-    grid dim right; refs carry a leading expert-singleton block dim."""
-    if has_bias:
-        x_ref, w_ref, b_ref = refs[:3]
-        out_refs = refs[3:]
-    else:
-        x_ref, w_ref = refs[:2]
-        b_ref = None
-        out_refs = refs[2:]
-    y_ref = out_refs[0]
-    f = pl.program_id(3)
-
-    @pl.when(f == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    x = x_ref[0]  # (block_m, bL)
-    w = w_ref[0, 0, 0]  # (bL, bR)
-    y_ref[0] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=y_ref.dtype)
-
-    if has_bias or activation is not None or save_preact:
-        @pl.when(f == d_in_b - 1)
+        @pl.when(f == n_f - 1)
         def _epilogue():
             z = y_ref[0]
             if has_bias:
@@ -188,212 +247,21 @@ def _fwd_kernel_batched(idx_ref, *refs, d_in_b: int,
             y_ref[0] = apply_activation(z, activation)
 
 
-# ---------------------------------------------------------------------------
-# Quantized forward (inference only): the slab enters the kernel as int8 and
-# is widened *in register* right before the MXU issue; the per-block f32
-# scale rides the scalar-prefetch channel (SMEM, next to the pattern — the
-# FPGA analogy: the fixed-point weight memory plus a tiny per-block exponent
-# ROM). The f32 accumulator is scaled per fan-in slot, so bias/activation in
-# the last-slot epilogue see fully dequantized values. HBM traffic for the
-# weights is 1 byte/element — certified by sparselint SL206: no
-# convert_element_type of the *whole* slab may appear outside the kernel.
-# ---------------------------------------------------------------------------
-
-
-def _fwd_kernel_quant(idx_ref, scale_ref, *refs, d_in_b: int,
-                      activation: Optional[str], has_bias: bool):
-    """refs: x, w(int8), [bias], y. Same schedule as ``_fwd_kernel``."""
-    if has_bias:
-        x_ref, w_ref, b_ref, y_ref = refs
-    else:
-        (x_ref, w_ref, y_ref), b_ref = refs, None
-    r = pl.program_id(1)
-    f = pl.program_id(2)
-
-    @pl.when(f == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    x = x_ref[...]  # (block_m, bL)
-    w = w_ref[0, 0].astype(x.dtype)  # int8 -> compute dtype, in register
-    s = scale_ref[r, f]  # per-block f32 scale from SMEM
-    y_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=y_ref.dtype) * s
-
-    if has_bias or activation is not None:
-        @pl.when(f == d_in_b - 1)
-        def _epilogue():
-            z = y_ref[...]
-            if has_bias:
-                z = z + b_ref[...].astype(z.dtype)
-            y_ref[...] = apply_activation(z, activation)
-
-
-def _fwd_kernel_quant_batched(idx_ref, scale_ref, *refs, d_in_b: int,
-                              activation: Optional[str], has_bias: bool):
-    """Expert-major quantized forward; scales are (E, n_rb, d_in_b)."""
-    if has_bias:
-        x_ref, w_ref, b_ref, y_ref = refs
-    else:
-        (x_ref, w_ref, y_ref), b_ref = refs, None
-    e = pl.program_id(0)
-    r = pl.program_id(2)
-    f = pl.program_id(3)
-
-    @pl.when(f == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    x = x_ref[0]  # (block_m, bL)
-    w = w_ref[0, 0, 0].astype(x.dtype)  # (bL, bR) int8 -> compute dtype
-    s = scale_ref[e, r, f]
-    y_ref[0] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=y_ref.dtype) * s
-
-    if has_bias or activation is not None:
-        @pl.when(f == d_in_b - 1)
-        def _epilogue():
-            z = y_ref[0]
-            if has_bias:
-                z = z + b_ref[...].astype(z.dtype)
-            y_ref[0] = apply_activation(z, activation)
-
-
-def _csd_spmm_fwd_quant(x, w, w_scale, block_idx, *, bias, activation,
-                        block_m, interpret):
-    """Unbatched quantized forward: w int8 (n_rb, d_in_b, bL, bR) with
-    scales (n_rb, d_in_b) f32; grid identical to the full-width path."""
-    m, n_in = x.shape
-    n_rb, d_in_b, bl, br = w.shape
-    if n_in % bl:
-        raise ValueError("n_in not divisible by block_in")
-    if m % block_m:
-        raise ValueError(f"M={m} not divisible by block_m={block_m}")
-
-    has_bias = bias is not None
-    grid = (m // block_m, n_rb, d_in_b)
-    kernel = functools.partial(_fwd_kernel_quant, d_in_b=d_in_b,
-                               activation=activation, has_bias=has_bias)
-    in_specs = [
-        pl.BlockSpec((block_m, bl),
-                     lambda i, r, f, idx, sc: (i, idx[r, f])),
-        pl.BlockSpec((1, 1, bl, br),
-                     lambda i, r, f, idx, sc: (r, f, 0, 0)),
-    ]
-    operands = [jnp.asarray(block_idx, jnp.int32),
-                jnp.asarray(w_scale, jnp.float32), x, w]
-    if has_bias:
-        in_specs.append(pl.BlockSpec(_bias_tile(br),
-                                     lambda i, r, f, idx, sc: (r, 0, 0)))
-        operands.append(bias.reshape(n_rb, 1, br))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((block_m, br),
-                                   lambda i, r, f, idx, sc: (i, r)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((m, n_rb * br), jnp.float32),
-        interpret=interpret,
-        **pallas_names("csd_spmm_fwd_int8"),
-    )(*operands)
-    return out.astype(x.dtype)
-
-
-def _csd_spmm_fwd_quant_batched(x, w, w_scale, block_idx, *, bias,
-                                activation, block_m, interpret):
-    """Expert-batched quantized forward: w int8 (E, n_rb, d_in_b, bL, bR)
-    with scales (E, n_rb, d_in_b) f32."""
-    e, m, n_in = x.shape
-    _, n_rb, d_in_b, bl, br = w.shape
-    if n_in % bl:
-        raise ValueError("n_in not divisible by block_in")
-    if m % block_m:
-        raise ValueError(f"M={m} not divisible by block_m={block_m}")
-
-    has_bias = bias is not None
-    grid = (e, m // block_m, n_rb, d_in_b)
-    kernel = functools.partial(_fwd_kernel_quant_batched, d_in_b=d_in_b,
-                               activation=activation, has_bias=has_bias)
-    in_specs = [
-        pl.BlockSpec((1, block_m, bl),
-                     lambda e, i, r, f, idx, sc: (e, i, idx[r, f])),
-        pl.BlockSpec((1, 1, 1, bl, br),
-                     lambda e, i, r, f, idx, sc: (e, r, f, 0, 0)),
-    ]
-    operands = [jnp.asarray(block_idx, jnp.int32),
-                jnp.asarray(w_scale, jnp.float32), x, w]
-    if has_bias:
-        in_specs.append(pl.BlockSpec((pl.squeezed,) + _bias_tile(br),
-                                     lambda e, i, r, f, idx, sc: (e, r, 0, 0)))
-        operands.append(bias.reshape(e, n_rb, 1, br))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_m, br),
-                                   lambda e, i, r, f, idx, sc: (e, i, r)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((e, m, n_rb * br), jnp.float32),
-        interpret=interpret,
-        **pallas_names("csd_spmm_fwd_int8_batched"),
-    )(*operands)
-    return out.astype(x.dtype)
-
-
-def _csd_spmm_fwd_batched(x, w, block_idx, *, bias, activation, save_preact,
-                          block_m, interpret):
-    """Expert-batched forward: x (E, M, n_in), w (E, n_rb, d_in_b, bL, bR),
-    one shared pattern prefetched once; grid (E, M/bm, n_rb, d_in_b)."""
-    e, m, n_in = x.shape
-    _, n_rb, d_in_b, bl, br = w.shape
-    if n_in % bl:
-        raise ValueError("n_in not divisible by block_in")
-    if m % block_m:
-        raise ValueError(f"M={m} not divisible by block_m={block_m}")
-    acc_dtype = jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float32) else x.dtype
-
-    has_bias = bias is not None
-    grid = (e, m // block_m, n_rb, d_in_b)
-    kernel = functools.partial(_fwd_kernel_batched, d_in_b=d_in_b,
-                               activation=activation, has_bias=has_bias,
-                               save_preact=save_preact)
-    in_specs = [
-        pl.BlockSpec((1, block_m, bl),
-                     lambda e, i, r, f, idx: (e, i, idx[r, f])),
-        pl.BlockSpec((1, 1, 1, bl, br),
-                     lambda e, i, r, f, idx: (e, r, f, 0, 0)),
-    ]
-    operands = [jnp.asarray(block_idx, jnp.int32), x, w]
-    if has_bias:
-        in_specs.append(pl.BlockSpec((pl.squeezed,) + _bias_tile(br),
-                                     lambda e, i, r, f, idx: (e, r, 0, 0)))
-        operands.append(bias.reshape(e, n_rb, 1, br))
-    out_spec = pl.BlockSpec((1, block_m, br),
-                            lambda e, i, r, f, idx: (e, i, r))
-    out_shape = jax.ShapeDtypeStruct((e, m, n_rb * br), acc_dtype)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=(out_spec, out_spec) if save_preact else out_spec,
-        ),
-        out_shape=(out_shape, out_shape) if save_preact else out_shape,
-        interpret=interpret,
-        **pallas_names("csd_spmm_fwd_batched"),
-    )(*operands)
-    if save_preact:
-        y, z = out
-        return y.astype(x.dtype), z.astype(x.dtype)
-    return out.astype(x.dtype)
+def _count_fwd(form: str, steps: int, k: int, d_in_b: int) -> None:
+    """Trace-time counters of the forward's tiling (like
+    ``repro_junction_dispatch_total``: per compiled call, no op in the
+    program): grid steps of each call, and the fan-in slots each step
+    reduces, per form and fan-in."""
+    reg = _obs_metrics.get_registry()
+    reg.counter(
+        "repro_junction_fwd_grid_steps_total",
+        "csd_spmm forward grid steps per traced call, by form",
+    ).inc(steps, form=form)
+    reg.gauge(
+        "repro_junction_fwd_slots_per_step",
+        "fan-in slots a csd_spmm forward grid step reduces, by form and "
+        "fan-in",
+    ).set(k, form=form, fan_in=d_in_b)
 
 
 def csd_spmm_fwd(
@@ -404,7 +272,8 @@ def csd_spmm_fwd(
     bias: Optional[jax.Array] = None,
     activation: Optional[str] = None,
     save_preact: bool = False,
-    block_m: int = 128,
+    block_m: Optional[int] = None,
+    fan_in_block: Optional[int] = None,
     interpret: bool = False,
     w_scale: Optional[jax.Array] = None,
 ):
@@ -416,7 +285,16 @@ def csd_spmm_fwd(
 
     Batched (expert-major) form: w (E, n_rb, d_in_b, bL, bR) with
     x (E, M, n_in) and bias (E, n_rb*bR) -> y (E, M, n_rb*bR); the expert
-    index is the leading grid dimension and the pattern is shared.
+    index is the leading grid dimension and the pattern is shared. The
+    plain form runs as the batched one with E = 1.
+
+    Grid ``(E, M/block_m, n_rb, d_in_b/k)``: each step reduces ``k``
+    fan-in slots of one output tile, which stays in VMEM across the
+    chunks; the epilogue fires on the last chunk. ``block_m`` and ``k``
+    come from the shapes (``fwd_tiling``) unless given (``fan_in_block``,
+    a divisor of ``d_in_b``, is a seam for tests); ``M`` must be a
+    multiple of ``block_m``. Each of the ``k`` x tiles has its own
+    BlockSpec, indexed through the scalar-prefetched pattern.
 
     ``save_preact=True`` additionally returns the pre-activation
     ``z = x @ W_sparse + bias`` (needed by the backward pass of non-masking
@@ -425,11 +303,13 @@ def csd_spmm_fwd(
     ``w_scale`` selects the int8-quantized forward (inference only, no
     VJP): ``w`` must be int8 with per-block scales ``(n_rb, d_in_b)``
     (resp. ``(E, n_rb, d_in_b)``) from ``core.quant.quantize_slab``;
-    dequantization is folded into the accumulate before the epilogue.
+    dequantization is folded into each slot's accumulate, before the
+    epilogue.
     """
     if activation is not None and activation not in ACTIVATIONS:
         raise ValueError(f"unsupported fused activation {activation!r}")
-    if w_scale is not None:
+    quant = w_scale is not None
+    if quant:
         if save_preact:
             raise ValueError(
                 "save_preact is unsupported on the quantized path "
@@ -437,68 +317,73 @@ def csd_spmm_fwd(
         if w.dtype != jnp.int8:
             raise ValueError(f"w_scale given but w.dtype={w.dtype}, "
                              f"expected int8")
-        if w.ndim == 5:
-            return _csd_spmm_fwd_quant_batched(
-                x, w, w_scale, block_idx, bias=bias, activation=activation,
-                block_m=block_m, interpret=interpret)
-        return _csd_spmm_fwd_quant(
-            x, w, w_scale, block_idx, bias=bias, activation=activation,
-            block_m=block_m, interpret=interpret)
-    if w.ndim == 5:
-        return _csd_spmm_fwd_batched(
-            x, w, block_idx, bias=bias, activation=activation,
-            save_preact=save_preact, block_m=block_m, interpret=interpret)
-    m, n_in = x.shape
-    n_rb, d_in_b, bl, br = w.shape
+    batched = w.ndim == 5
+    if not batched:
+        x, w = x[None], w[None]
+        bias = None if bias is None else bias[None]
+        w_scale = None if w_scale is None else w_scale[None]
+    e, m, n_in = x.shape
+    _, n_rb, d_in_b, bl, br = w.shape
     if n_in % bl:
         raise ValueError("n_in not divisible by block_in")
+    has_bias = bias is not None
+    n_out = 2 if save_preact else 1
+    block_m, k = fwd_tiling(m, d_in_b, bl, br, x_dtype=x.dtype,
+                            w_dtype=w.dtype, n_out=n_out, has_bias=has_bias,
+                            block_m=block_m, fan_in_block=fan_in_block)
     if m % block_m:
         raise ValueError(f"M={m} not divisible by block_m={block_m}")
-    acc_dtype = jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float32) else x.dtype
+    acc_dtype = jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float32) \
+        else x.dtype
 
-    has_bias = bias is not None
-    grid = (m // block_m, n_rb, d_in_b)
-    kernel = functools.partial(_fwd_kernel, d_in_b=d_in_b,
+    # scalar prefetch: the pattern (and the int8 scales)
+    prefetch = [jnp.asarray(block_idx, jnp.int32)]
+    if quant:
+        prefetch.append(jnp.asarray(w_scale, jnp.float32))
+    in_specs = [
+        # x tile j of the step: row block i, the left block the pattern
+        # names for slot f*k + j of right block r
+        pl.BlockSpec((1, block_m, bl),
+                     lambda e, i, r, f, idx, *_, j=j: (e, i, idx[r, f * k + j]))
+        for j in range(k)]
+    # weight block: slots f*k .. f*k+k-1 of right block r, contiguous
+    in_specs.append(pl.BlockSpec((1, 1, k, bl, br),
+                                 lambda e, i, r, f, *_: (e, r, f, 0, 0)))
+    operands = [x] * k + [w]
+    if has_bias:
+        # bias as (E, n_rb, 1, bR): one right-block row per output tile
+        in_specs.append(pl.BlockSpec((pl.squeezed,) + _bias_tile(br),
+                                     lambda e, i, r, f, *_: (e, r, 0, 0)))
+        operands.append(bias.reshape(e, n_rb, 1, br))
+    out_spec = pl.BlockSpec((1, block_m, br),
+                            lambda e, i, r, f, *_: (e, i, r))
+    out_shape = jax.ShapeDtypeStruct((e, m, n_rb * br), acc_dtype)
+    grid = (e, m // block_m, n_rb, d_in_b // k)
+    form = {(False, False): "plain", (False, True): "batched",
+            (True, False): "quant", (True, True): "quant_batched"}[
+                (quant, batched)]
+    kernel_name = "csd_spmm_fwd" + ("_int8" if quant else "") \
+        + ("_batched" if batched else "")
+    _count_fwd(form, int(np.prod(grid)), k, d_in_b)
+    kernel = functools.partial(_fwd_kernel, k=k, d_in_b=d_in_b, quant=quant,
                                activation=activation, has_bias=has_bias,
                                save_preact=save_preact)
-    in_specs = [
-        # x tile: row-block i, left-block chosen by the pattern.
-        pl.BlockSpec((block_m, bl),
-                     lambda i, r, f, idx: (i, idx[r, f])),
-        # w tile: one (bL, bR) block per (r, f).
-        pl.BlockSpec((1, 1, bl, br),
-                     lambda i, r, f, idx: (r, f, 0, 0)),
-    ]
-    operands = [jnp.asarray(block_idx, jnp.int32), x, w]
-    if has_bias:
-        # bias as (n_rb, 1, bR): one right-block row per output tile.
-        in_specs.append(pl.BlockSpec(_bias_tile(br),
-                                     lambda i, r, f, idx: (r, 0, 0)))
-        operands.append(bias.reshape(n_rb, 1, br))
-    out_spec = pl.BlockSpec((block_m, br), lambda i, r, f, idx: (i, r))
-    out_shape = jax.ShapeDtypeStruct((m, n_rb * br), acc_dtype)
-    if save_preact:
-        out_specs = (out_spec, out_spec)
-        out_shapes = (out_shape, out_shape)
-    else:
-        out_specs = out_spec
-        out_shapes = out_shape
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
-            out_specs=out_specs,
+            out_specs=(out_spec,) * n_out if save_preact else out_spec,
         ),
-        out_shape=out_shapes,
+        out_shape=(out_shape,) * n_out if save_preact else out_shape,
         interpret=interpret,
-        **pallas_names("csd_spmm_fwd"),
-    )(*operands)
-    if save_preact:
-        y, z = out
-        return y.astype(x.dtype), z.astype(x.dtype)
-    return out.astype(x.dtype)
+        **pallas_names(kernel_name),
+    )(*prefetch, *operands)
+    outs = out if save_preact else (out,)
+    outs = tuple(o.astype(x.dtype) if batched else o[0].astype(x.dtype)
+                 for o in outs)
+    return outs if save_preact else outs[0]
 
 
 # ---------------------------------------------------------------------------

@@ -118,6 +118,33 @@ def run_replicated(fn, mesh, *args):
                          check_vma=False)(*args)
 
 
+def _pad_rows(x, batched: bool, block_m: Optional[int]):
+    """Flatten ``x``'s leading dims to M rows (per expert when batched:
+    the M axis is -2 in both layouts) and pad M for a Pallas grid: to a
+    multiple of ``block_m`` when it is given, else to the forward's derived
+    row count (``csd_spmm.fwd_rows``). Returns ``(padded, m)``."""
+    xf = x.reshape(((x.shape[0],) if batched else ()) + (-1, x.shape[-1]))
+    m = xf.shape[-2]
+    rows = -(-m // block_m) * block_m if block_m \
+        else csd_spmm.fwd_rows(m, x.dtype)
+    if rows > m:
+        xf = jnp.pad(xf, [(0, 0)] * (xf.ndim - 2) + [(0, rows - m), (0, 0)])
+    return xf, m
+
+
+def _unpad_rows(y, x, m: int):
+    """Inverse of ``_pad_rows`` on a result: drop the padded rows and
+    restore ``x``'s leading dims."""
+    y = y[..., :m, :]
+    return y.reshape(x.shape[:-1] + (y.shape[-1],))
+
+
+def _bwd_block_m(block_m: Optional[int], rows: int) -> int:
+    """Row block of the dx/dw kernels: the explicit one, else 128, or the
+    whole call where ``_pad_rows`` left fewer rows than that."""
+    return block_m or min(rows, 128)
+
+
 # Static pattern arrays are hashed by id for custom_vjp staticness; wrap them
 # in a hashable carrier.
 class _Pat:
@@ -482,7 +509,7 @@ def _fwd_impl(x, w, b, pat, has_bias, activation, backend, dataflow,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _csd_matmul(x, w, b, pat: _Pat, has_bias: bool,
                 activation: Optional[str], backend: str, dataflow: str,
-                block_m: int, interpret: bool):
+                block_m: Optional[int], interpret: bool):
     y, _ = _fwd_impl(x, w, b, pat, has_bias, activation, backend, dataflow,
                      block_m, interpret)
     return y
@@ -522,6 +549,7 @@ def _bwd_vjp(pat, has_bias, activation, backend, dataflow, block_m,
     dy = dy.astype(x.dtype)
     batched = w.ndim == 5
     if backend == "pallas":
+        block_m = _bwd_block_m(block_m, x.shape[-2])
         # fused backward epilogue: the raw cotangent streams into the
         # BP/UP kernels which mask it tile-by-tile from aux (and fold the
         # bias cotangent into the UP sweep) — no separate elementwise op,
@@ -704,8 +732,9 @@ def _spmd_fwd_call(x, w, b, spat, has_bias, activation, backend, block_m,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9,
                                                     10, 11))
 def _csd_matmul_spmd(x, w, b, spat: _ShardPat, has_bias: bool,
-                     activation: Optional[str], backend: str, block_m: int,
-                     interpret: bool, mesh, axis: str, lead: tuple):
+                     activation: Optional[str], backend: str,
+                     block_m: Optional[int], interpret: bool, mesh,
+                     axis: str, lead: tuple):
     return _spmd_fwd_call(x, w, b, spat, has_bias, activation, backend,
                           block_m, interpret, mesh, axis, lead,
                           want_aux=False)
@@ -744,19 +773,20 @@ def _spmd_bwd_vjp(spat, has_bias, activation, backend, block_m, interpret,
     def local(xl, wl, bll, auxl, dyl):
         idx, oidx, oslot, ovalid = _local_pattern(spat, axis)
         if backend == "pallas":
+            bm = _bwd_block_m(block_m, xl.shape[-2])
             dxl = csd_spmm.csd_spmm_dx(
                 dyl, wl, oidx, oslot, out_valid=ovalid, aux=auxl,
-                activation=activation, block_m=block_m,
+                activation=activation, block_m=bm,
                 interpret=interpret)
             if has_bias:
                 dwl, dbl = csd_spmm.csd_spmm_dw(
                     xl, dyl, idx, block_in=bl_, block_out=br_, aux=auxl,
-                    activation=activation, want_db=True, block_m=block_m,
+                    activation=activation, want_db=True, block_m=bm,
                     interpret=interpret)
             else:
                 dwl = csd_spmm.csd_spmm_dw(
                     xl, dyl, idx, block_in=bl_, block_out=br_, aux=auxl,
-                    activation=activation, block_m=block_m,
+                    activation=activation, block_m=bm,
                     interpret=interpret)
                 dbl = jnp.zeros((0,), jnp.float32)
         else:
@@ -811,19 +841,11 @@ def _csd_matmul_sharded(x, w, pattern, bias, activation, backend, block_m,
     has_bias = bias is not None
     b = bias if has_bias else jnp.zeros((0,), x.dtype)
     if backend == "pallas":
-        n_in = x.shape[-1]
-        xf = x.reshape(((x.shape[0],) if batched else ()) + (-1, n_in))
-        m = xf.shape[-2]
-        pad = (-m) % block_m
-        if pad:
-            widths = [(0, 0)] * (xf.ndim - 2) + [(0, pad), (0, 0)]
-            xf = jnp.pad(xf, widths)
+        xf, m = _pad_rows(x, batched, block_m)
         lead = (None,) * (xf.ndim - 1)
         y = _csd_matmul_spmd(xf, w, b, spat, has_bias, activation, backend,
                              block_m, interpret, mesh, axis, lead)
-        if pad:
-            y = y[..., :m, :]
-        return y.reshape(x.shape[:-1] + (y.shape[-1],))
+        return _unpad_rows(y, x, m)
     if lead_spec is None:
         lead = (None,) * (x.ndim - 1)
     else:
@@ -849,19 +871,11 @@ def _quant_matmul(x, w, w_scale, pat, bias, activation, backend, dataflow,
     batched = w.ndim == 5
     has_bias = bias is not None
     if backend == "pallas":
-        n_in = x.shape[-1]
-        xf = x.reshape(((x.shape[0],) if batched else ()) + (-1, n_in))
-        m = xf.shape[-2]
-        pad = (-m) % block_m
-        if pad:
-            widths = [(0, 0)] * (xf.ndim - 2) + [(0, pad), (0, 0)]
-            xf = jnp.pad(xf, widths)
+        xf, m = _pad_rows(x, batched, block_m)
         y = csd_spmm.csd_spmm_fwd(
             xf, w, pat.block_idx, bias=bias, activation=activation,
             block_m=block_m, interpret=interpret, w_scale=w_scale)
-        if pad:
-            y = y[..., :m, :]
-        return y.reshape(x.shape[:-1] + (y.shape[-1],))
+        return _unpad_rows(y, x, m)
     if batched:
         z = _xla_fwd_quant_batched(x, w, w_scale, pat, dataflow)
     elif dataflow == "scatter":
@@ -925,17 +939,9 @@ def _quant_matmul_sharded(x, w, w_scale, pattern, bias, activation, backend,
         return fn(xf, w, w_scale, b)
 
     if backend == "pallas":
-        n_in = x.shape[-1]
-        xf = x.reshape(((x.shape[0],) if batched else ()) + (-1, n_in))
-        m = xf.shape[-2]
-        pad = (-m) % block_m
-        if pad:
-            widths = [(0, 0)] * (xf.ndim - 2) + [(0, pad), (0, 0)]
-            xf = jnp.pad(xf, widths)
+        xf, m = _pad_rows(x, batched, block_m)
         y = run(xf, (None,) * (xf.ndim - 1))
-        if pad:
-            y = y[..., :m, :]
-        return y.reshape(x.shape[:-1] + (y.shape[-1],))
+        return _unpad_rows(y, x, m)
     if lead_spec is None:
         lead = (None,) * (x.ndim - 1)
     else:
@@ -956,7 +962,7 @@ def csd_matmul(
     activation: Optional[str] = None,
     backend: str = "auto",
     dataflow: str = "gather",
-    block_m: int = 128,
+    block_m: Optional[int] = None,
     interpret: bool = False,
     mesh=None,
     axis: Optional[str] = None,
@@ -981,9 +987,11 @@ def csd_matmul(
 
     ``activation`` is ``None | "relu" | "gelu"`` (gelu = tanh approximation,
     matching the model stack's activation registry). Leading dims are
-    flattened to M (per expert in the batched form) and padded to
-    ``block_m`` for the Pallas path; the XLA path keeps leading dims intact
-    so GSPMD preserves their sharding. The pattern is compile-time static.
+    flattened to M (per expert in the batched form) and padded for the
+    Pallas path: to ``block_m`` when it is given, else to the rows the
+    forward derives its row block from (``csd_spmm.fwd_tiling``); the XLA
+    path keeps leading dims intact so GSPMD preserves their sharding. The
+    pattern is compile-time static.
 
     Sharded (model-parallel) form: pass ``mesh`` and ``axis`` (a mesh axis
     name) to partition the pattern and slab over ``mesh.shape[axis]``
@@ -1045,7 +1053,8 @@ def csd_matmul(
         if ent is not None:
             backend = str(ent["backend"])
             dataflow = str(ent.get("dataflow", dataflow))
-            block_m = int(ent.get("block_m", block_m))
+            if ent.get("block_m") is not None:
+                block_m = int(ent["block_m"])
         else:
             backend = _resolve(backend)
     if backend == "dense" and (quant or sharded):
@@ -1079,20 +1088,10 @@ def csd_matmul(
     has_bias = bias is not None
     b = bias if has_bias else jnp.zeros((0,), x.dtype)
     if backend == "pallas":
-        n_in = x.shape[-1]
-        # after this reshape the M axis is -2 in both layouts (batched
-        # keeps E as axis 0), so pad/slice/unflatten share one form
-        xf = x.reshape(((x.shape[0],) if batched else ()) + (-1, n_in))
-        m = xf.shape[-2]
-        pad = (-m) % block_m
-        if pad:
-            widths = [(0, 0)] * (xf.ndim - 2) + [(0, pad), (0, 0)]
-            xf = jnp.pad(xf, widths)
+        xf, m = _pad_rows(x, batched, block_m)
         y = _csd_matmul(xf, w, b, pat, has_bias, activation, backend,
                         dataflow, block_m, interpret)
-        if pad:
-            y = y[..., :m, :]
-        return y.reshape(x.shape[:-1] + (y.shape[-1],))
+        return _unpad_rows(y, x, m)
     # xla: leading dims flow through untouched (sharding preserved)
     return _csd_matmul(x, w, b, pat, has_bias, activation, backend,
                        dataflow, block_m, interpret)

@@ -128,7 +128,7 @@ def _explain(cache) -> dict:
         else:
             print(f"{key}\n  -> {ent.get('backend')}"
                   f"/{ent.get('dataflow', '-')}"
-                  f" bm{ent.get('block_m', '-')}"
+                  f" bm{ent.get('block_m') or 'derived'}"
                   f" ({ent.get('score_us')}us, "
                   f"{ent.get('speedup_vs_heuristic')}x vs "
                   f"{ent.get('heuristic')}){extra}")

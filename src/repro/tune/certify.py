@@ -17,17 +17,18 @@ same has-teeth contract as ``lint --selftest-inject``.
 """
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 
-def certify_junction(bp, m: int, block_m: int, *, E: int = 0,
+def certify_junction(bp, m: int, block_m: Optional[int], *, E: int = 0,
                      activation: str = "relu",
                      dtype=None) -> Tuple[bool, List]:
     """Certify one Pallas ``csd_spmm_fwd`` candidate (SL101–SL105).
 
     Returns ``(ok, findings)``. ``m`` is the logical row count; the entry
-    point pads M to ``block_m``, so the capture sees post-pad shapes —
-    exactly what the grid pass certifies against.
+    point pads M to ``block_m`` (to ``csd_spmm.fwd_rows`` when it is None,
+    the derived tiling), so the capture sees post-pad shapes — exactly
+    what the grid pass certifies against.
     """
     import jax.numpy as jnp
 
@@ -37,9 +38,10 @@ def certify_junction(bp, m: int, block_m: int, *, E: int = 0,
     from ..kernels import csd_spmm
 
     batched = E > 0
-    mp = m + (-m) % block_m
-    name = f"tune:csd_spmm_fwd_bm{block_m}" + ("_5d" if batched else "")
     dt = jnp.float32 if dtype is None else dtype
+    mp = m + (-m) % block_m if block_m else csd_spmm.fwd_rows(m, dt)
+    name = f"tune:csd_spmm_fwd_bm{block_m or 'derived'}" \
+        + ("_5d" if batched else "")
 
     def build():
         lead = (E,) if batched else ()
@@ -51,8 +53,7 @@ def certify_junction(bp, m: int, block_m: int, *, E: int = 0,
             csd_spmm.csd_spmm_fwd, x, w, bp.block_idx, bias=bias,
             activation=activation, block_m=block_m, name=name)
 
-    case = grid_pass.KernelCase(name, build,
-                                epilogue_axis=3 if batched else 2)
+    case = grid_pass.KernelCase(name, build, epilogue_axis=3)
     try:
         launch = case.build()
     except Exception as e:  # unlaunchable config = rejected, not fatal

@@ -17,7 +17,8 @@ Candidate axes (the software form of the paper's flexible ``z``):
 * dataflow ∈ {gather, scatter} for the XLA lowering — scatter gathers
   weights instead of activations, so it is M-independent and wins the
   skinny-M decode regime where gather falls off a cliff.
-* block_m for Pallas grids.
+* block_m for Pallas grids: the forward's derived tiling
+  (``csd_spmm.fwd_tiling``, ``block_m`` None) or a fixed row block.
 
 Scoring: skinny-M regimes (M ≤ 32 — decode) score by forward time; larger
 regimes (training/prefill) score by a full ``value_and_grad`` step so the
@@ -39,19 +40,20 @@ from . import certify as _certify
 # time only (no backward runs at decode).
 SKINNY_M = 32
 
-PALLAS_BLOCK_MS = (128, 256)
+# None: the row block and fan-in chunk the forward derives from the shapes
+PALLAS_BLOCK_MS = (None, 128, 256)
 
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:
     backend: str
     dataflow: str = "gather"
-    block_m: int = 128
+    block_m: Optional[int] = None
 
     @property
     def label(self) -> str:
         if self.backend == "pallas":
-            return f"pallas/bm{self.block_m}"
+            return f"pallas/bm{self.block_m or 'derived'}"
         if self.backend == "dense":
             return "dense"
         return f"xla/{self.dataflow}"
@@ -73,7 +75,7 @@ def junction_candidates(*, quant: bool = False, sharded: bool = False,
 def _heuristic_candidate() -> Candidate:
     """What today's static ``_resolve("auto")`` would pick — the baseline
     every tuned decision is compared against."""
-    return Candidate("pallas" if on_tpu() else "xla", "gather", 128)
+    return Candidate("pallas" if on_tpu() else "xla", "gather")
 
 
 def _reg():

@@ -26,18 +26,56 @@ SPMM_CASES = [
 ]
 
 
+def _d_in_b(n_in, n_out, bl, br, rho):
+    return make_block_pattern(n_in, n_out, rho, block_in=bl,
+                              block_out=br).d_in_b
+
+
+def _part_block(d_in_b):
+    """The largest divisor of the fan-in between 1 and the whole, or None
+    (a fan-in of 1, 2 or a prime)."""
+    return next((k for k in range(d_in_b - 1, 1, -1) if d_in_b % k == 0),
+                None)
+
+
+def _fan_in_block(fan_in, d_in_b):
+    """Fan-in slots per grid step for a test mode: ``whole`` (derived, the
+    whole fan-in at these sizes), ``one`` (one slot a step, the unfolded
+    schedule) or ``part`` (several chunks of several slots)."""
+    if fan_in == "whole":
+        return None
+    if fan_in == "one":
+        return 1
+    return _part_block(d_in_b)
+
+
+def _fan_in_params(cases, geometry):
+    """(case, fan_in) pairs; ``part`` only where the fan-in has a divisor
+    between 1 and the whole."""
+    return [pytest.param(c, fi, id=f"{i}-{fi}")
+            for i, c in enumerate(cases)
+            for fi in ("whole", "one", "part")
+            if fi != "part" or _part_block(_d_in_b(*geometry(c)))]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("case", SPMM_CASES)
-def test_csd_spmm_fwd(case, dtype):
+@pytest.mark.parametrize(
+    "case,fan_in", _fan_in_params(SPMM_CASES, lambda c: c[:5]))
+def test_csd_spmm_fwd(case, fan_in, dtype):
     n_in, n_out, bl, br, rho, m, bm = case
     bp = make_block_pattern(n_in, n_out, rho, block_in=bl, block_out=br,
                             seed=1)
     x = jax.random.normal(jax.random.key(0), (m, n_in), dtype)
     w = jax.random.normal(jax.random.key(1),
                           (bp.n_rb, bp.d_in_b, bl, br), dtype)
+    k = _fan_in_block(fan_in, bp.d_in_b)
+    if k is None:
+        assert csd_spmm.fwd_tiling(m, bp.d_in_b, bl, br, x_dtype=dtype,
+                                   w_dtype=dtype, block_m=bm) \
+            == (bm, bp.d_in_b)
     y_ref = ref.csd_spmm_fwd_ref(x, w, bp.block_idx)
     y = csd_spmm.csd_spmm_fwd(x, w, bp.block_idx, block_m=bm,
-                              interpret=True)
+                              fan_in_block=k, interpret=True)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32), **_tol(dtype))
 
@@ -96,8 +134,9 @@ BATCHED_CASES = [
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("case", BATCHED_CASES)
-def test_csd_spmm_fwd_batched(case, dtype):
+@pytest.mark.parametrize(
+    "case,fan_in", _fan_in_params(BATCHED_CASES, lambda c: c[1:6]))
+def test_csd_spmm_fwd_batched(case, fan_in, dtype):
     e, n_in, n_out, bl, br, rho, m, bm = case
     bp = make_block_pattern(n_in, n_out, rho, block_in=bl, block_out=br,
                             seed=1)
@@ -106,6 +145,7 @@ def test_csd_spmm_fwd_batched(case, dtype):
                           (e, bp.n_rb, bp.d_in_b, bl, br), dtype)
     y_ref = ref.csd_spmm_fwd_batched_ref(x, w, bp.block_idx)
     y = csd_spmm.csd_spmm_fwd(x, w, bp.block_idx, block_m=bm,
+                              fan_in_block=_fan_in_block(fan_in, bp.d_in_b),
                               interpret=True)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32), **_tol(dtype))
@@ -139,27 +179,58 @@ def test_csd_spmm_dx_dw_batched(case):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_csd_spmm_fwd_batched_epilogue():
-    """Fused bias+activation in the batched kernel == epilogue outside."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("fan_in", ["whole", "one", "part"])
+@pytest.mark.parametrize("batched", [True, False], ids=["5d", "4d"])
+def test_csd_spmm_fwd_batched_epilogue(batched, fan_in, dtype):
+    """Fused bias+activation == epilogue outside, in the batched and the
+    plain kernel. With the fan-in split over chunks (``one``, ``part``) the
+    epilogue must fire once, on the last chunk: a bias added per chunk or
+    an activation of a partial sum would show."""
     e, n_in, n_out, bl, br, m, bm = 3, 64, 48, 8, 8, 16, 8
     bp = make_block_pattern(n_in, n_out, 0.5, block_in=bl, block_out=br,
                             seed=3)
-    x = jax.random.normal(jax.random.key(5), (e, m, n_in))
+    x = jax.random.normal(jax.random.key(5), (e, m, n_in), dtype)
     w = jax.random.normal(jax.random.key(6),
-                          (e, bp.n_rb, bp.d_in_b, bl, br))
-    b = jax.random.normal(jax.random.key(7), (e, n_out))
+                          (e, bp.n_rb, bp.d_in_b, bl, br), dtype)
+    b = jax.random.normal(jax.random.key(7), (e, n_out), dtype)
+    z = ref.csd_spmm_fwd_batched_ref(x, w, bp.block_idx).astype(
+        jnp.float32) + b[:, None].astype(jnp.float32)
+    if not batched:
+        x, w, b, z = x[0], w[0], b[0], z[0]
+    k = _fan_in_block(fan_in, bp.d_in_b)
+    tol = _tol(dtype) if dtype == jnp.bfloat16 \
+        else dict(atol=1e-5, rtol=1e-5)
+
+    def close(a, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(want, np.float32), **tol)
+
     y = csd_spmm.csd_spmm_fwd(x, w, bp.block_idx, bias=b,
                               activation="relu", block_m=bm,
-                              interpret=True)
-    z = ref.csd_spmm_fwd_batched_ref(x, w, bp.block_idx) + b[:, None]
-    np.testing.assert_allclose(y, jax.nn.relu(z), atol=1e-5, rtol=1e-5)
-    # save_preact returns the batched pre-activation alongside gelu output
+                              fan_in_block=k, interpret=True)
+    close(y, jax.nn.relu(z))
+    # save_preact returns the pre-activation alongside gelu output
     y2, z2 = csd_spmm.csd_spmm_fwd(x, w, bp.block_idx, bias=b,
                                    activation="gelu", save_preact=True,
-                                   block_m=bm, interpret=True)
-    np.testing.assert_allclose(z2, z, atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(y2, jax.nn.gelu(z, approximate=True),
-                               atol=1e-5, rtol=1e-5)
+                                   block_m=bm, fan_in_block=k,
+                                   interpret=True)
+    close(z2, z)
+    close(y2, jax.nn.gelu(z, approximate=True))
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["4d", "5d"])
+def test_csd_spmm_fwd_refuses_non_dividing_fan_in_block(batched):
+    """A fan-in chunk must divide the fan-in: every chunk's weight block
+    lies inside the slab, so no step is masked."""
+    bp = make_block_pattern(64, 64, 0.5, block_in=8, block_out=8, seed=1)
+    assert bp.d_in_b == 4
+    lead = (2,) if batched else ()
+    x = jnp.zeros(lead + (16, 64))
+    w = jnp.zeros(lead + (bp.n_rb, bp.d_in_b, 8, 8))
+    with pytest.raises(ValueError, match="does not divide"):
+        csd_spmm.csd_spmm_fwd(x, w, bp.block_idx, block_m=8, fan_in_block=3,
+                              interpret=True)
 
 
 def test_csd_matmul_grad_matches_dense_oracle():
@@ -181,14 +252,34 @@ def test_csd_matmul_grad_matches_dense_oracle():
     np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
 
 
-def test_csd_matmul_xla_equals_pallas():
+@pytest.mark.parametrize("experts", [0, 3], ids=["4d", "5d"])
+@pytest.mark.parametrize("rows,dtype,block_m", [
+    ((4, 7), jnp.float32, 8),     # odd M: padding to the given block
+    ((8,), jnp.float32, None),    # one f32 sublane tile, derived
+    ((8,), jnp.bfloat16, None),   # half a bf16 tile: padded to 16 rows
+], ids=["f32-bm8", "f32-8rows", "bf16-8rows"])
+def test_csd_matmul_xla_equals_pallas(rows, dtype, block_m, experts):
     bp = make_block_pattern(64, 64, 0.25, block_in=16, block_out=16, seed=3)
-    x = jax.random.normal(jax.random.key(5), (4, 7, 64))  # odd M: padding
-    w = jax.random.normal(jax.random.key(6), (bp.n_rb, bp.d_in_b, 16, 16))
+    lead = (experts,) if experts else ()
+    x = jax.random.normal(jax.random.key(5), lead + rows + (64,), dtype)
+    w = jax.random.normal(jax.random.key(6),
+                          lead + (bp.n_rb, bp.d_in_b, 16, 16), dtype)
     y1 = ops.csd_matmul(x, w, bp, backend="xla")
-    y2 = ops.csd_matmul(x, w, bp, backend="pallas", block_m=8,
+    y2 = ops.csd_matmul(x, w, bp, backend="pallas", block_m=block_m,
                         interpret=True)
-    np.testing.assert_allclose(y1, y2, atol=1e-5, rtol=1e-5)
+    tol = _tol(dtype) if dtype == jnp.bfloat16 \
+        else dict(atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(y1, np.float32),
+                               np.asarray(y2, np.float32), **tol)
+    if block_m is None:
+        # the row block is the rows the call has, to a whole sublane tile:
+        # no padding to 128
+        from repro.analysis.capture import capture_launch
+        launch = capture_launch(ops.csd_matmul, x, w, bp, backend="pallas",
+                                interpret=True)
+        assert launch.out_specs[0].block_shape[1] == \
+            csd_spmm.sublane_rows(dtype)
+        assert launch.grid[1] == 1
 
 
 # -- flash attention sweep ------------------------------------------------------

@@ -346,6 +346,36 @@ def test_dispatch_counter_counts_at_trace_time():
     assert c.value(backend="xla", form="plain") == before + 1
 
 
+def test_fwd_tiling_counters_count_at_trace_time():
+    """The forward's grid steps per call and fan-in slots per step, counted
+    when a call is traced: a plain call over 24 rows whose derived tiling
+    takes the whole fan-in (4 slots) in one step per right block, and a
+    batched call with 2 slots a step over 3 experts x 2 row blocks."""
+    from repro.kernels import csd_spmm
+    reg = metrics.get_registry()
+    steps = reg.counter("repro_junction_fwd_grid_steps_total")
+    slots = reg.gauge("repro_junction_fwd_slots_per_step")
+    bp = make_block_pattern(64, 32, 0.5, block_in=8, block_out=8, seed=0)
+    assert (bp.n_rb, bp.d_in_b) == (4, 4)
+    w = jnp.zeros((bp.n_rb, bp.d_in_b, 8, 8))
+    before = steps.value(form="plain")
+    f = jax.jit(lambda x, w: csd_spmm.csd_spmm_fwd(
+        x, w, bp.block_idx, interpret=True))
+    f(jnp.zeros((24, 64)), w)
+    f(jnp.zeros((24, 64)), w)  # cached executable: no new count
+    # grid (1, 1 row block of 24, 4 right blocks, 1 chunk)
+    assert steps.value(form="plain") == before + 4
+    assert slots.value(form="plain", fan_in=4) == 4
+
+    before = steps.value(form="batched")
+    csd_spmm.csd_spmm_fwd(jnp.zeros((3, 16, 64)), jnp.zeros((3,) + w.shape),
+                          bp.block_idx, block_m=8, fan_in_block=2,
+                          interpret=True)
+    # grid (3 experts, 2 row blocks, 4 right blocks, 2 chunks)
+    assert steps.value(form="batched") == before + 3 * 2 * 4 * 2
+    assert slots.value(form="batched", fan_in=4) == 2
+
+
 # ---------------------------------------------------------------------------
 # surfaces: HTTP endpoint, timed_call
 # ---------------------------------------------------------------------------

@@ -92,11 +92,13 @@ def test_capture_records_real_launch():
                   jnp.float32)
     launch = capture_launch(csd_spmm.csd_spmm_fwd, x, w, bp.block_idx,
                             block_m=128)
-    assert launch.grid == (1, bp.n_rb, bp.d_in_b)
+    # (E = 1, row blocks, right blocks, fan-in chunks): the whole fan-in
+    # is reduced in one step
+    assert launch.grid == (1, 1, bp.n_rb, 1)
     assert launch.num_scalar_prefetch == 1
     # index maps evaluate with the real pattern array
-    blk = launch.eval_index_map(launch.in_specs[0], (0, 1, 0))
-    assert blk == (0, int(bp.block_idx[1, 0]))
+    blk = launch.eval_index_map(launch.in_specs[0], (0, 0, 1, 0))
+    assert blk == (0, 0, int(bp.block_idx[1, 0]))
 
 
 # ---------------------------------------------------------------------------

@@ -175,6 +175,54 @@ def test_batched_fwd_and_gradient_compile(one_chip):
         "csd_spmm_dw_batched"}
 
 
+# the cells' junctions at 128 x 128 tiles (bench/configs): qwen2_7b up
+# (28 -> 148 blocks, fan-in 14) and down (148 -> 28, fan-in 111), granite's
+# expert up (8 -> 4, fan-in 4) and down (4 -> 8, fan-in 3)
+CELL_JUNCTIONS = {"qwen_up": (3584, 18944, 0.5), "qwen_down": (18944, 3584, 0.75),
+                  "granite_up": (1024, 512, 0.5),
+                  "granite_down": (512, 1024, 0.75)}
+
+
+@pytest.mark.parametrize("junction,experts,rows", [
+    ("qwen_up", 0, 8), ("qwen_up", 0, 512),
+    ("qwen_down", 0, 8), ("qwen_down", 0, 512),
+    ("granite_up", 32, 5120), ("granite_down", 32, 5120),
+], ids=["qwen_up-decode", "qwen_up-prefill", "qwen_down-decode",
+        "qwen_down-prefill", "granite_up-train", "granite_down-train"])
+def test_folded_fwd_compiles_at_cell_widths(one_chip, junction, experts,
+                                            rows):
+    """The forward as ``csd_matmul`` runs it in the cells (decode's 8 rows,
+    prefill's 512, 32 experts of 5,120 rows): it compiles at the
+    compiler's default VMEM limit under its kernel name, and the launch
+    sparselint captures at these shapes has no finding: its per-step
+    working set fits SL104's default budget, its output tiles are
+    revisited in consecutive chunks (SL101) and the epilogue chunk is the
+    last (SL103)."""
+    from repro.analysis import grid_pass
+    from repro.analysis.capture import capture_launch
+    from repro.core.block_pattern import make_block_pattern
+    bp = make_block_pattern(*CELL_JUNCTIONS[junction], block_in=128,
+                            block_out=128, seed=0)
+    lead = (experts,) if experts else ()
+    w_shape = lead + (bp.n_rb, bp.d_in_b, bp.block_in, bp.block_out)
+
+    def fwd(x, w):
+        return ops.csd_matmul(x, w, bp, backend="pallas")
+
+    kernels = _compile(fwd, _spec(lead + (rows, bp.n_in), BF16, one_chip),
+                       _spec(w_shape, BF16, one_chip))
+    assert kernels == {"csd_spmm_fwd_batched" if experts
+                       else "csd_spmm_fwd": 1}
+    launch = capture_launch(fwd, jnp.zeros(lead + (rows, bp.n_in), BF16),
+                            jnp.zeros(w_shape, BF16))
+    case = grid_pass.KernelCase(junction, lambda: launch, epilogue_axis=3)
+    findings, cost = grid_pass.analyze_launch(launch, case)
+    assert findings == [], [f.message for f in findings]
+    assert cost["vmem_bytes_per_step"] <= grid_pass.DEFAULT_VMEM_BUDGET
+    # the fold engaged: fewer grid steps than one per fan-in slot
+    assert launch.grid[3] < bp.d_in_b or bp.d_in_b == 1
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_decode_compiles(one_chip, quant):
     slots, page_size, pages_per_seq, pool = 4, 16, 40, 161
