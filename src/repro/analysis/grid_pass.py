@@ -309,18 +309,21 @@ def _paged_decode_case() -> KernelCase:
         import jax.numpy as jnp
         import numpy as _np
         from ..kernels.flash_attention import _paged_decode_pallas
-        b, hkv, g, dh, page, npg, pool = 2, 2, 2, 64, 8, 4, 9
+        # the production page (16 tokens, bf16); 37 pages take the derived
+        # 32 a step and a 5-page tail block. Row 0 ends in block 0 (block
+        # 1 repeats its pages), row 1 fills the table
+        b, hkv, g, dh, page, npg, pool = 2, 2, 2, 128, 16, 37, 40
         q = jnp.zeros((b, hkv, g, dh), jnp.bfloat16)
         kp = jnp.zeros((pool, page, hkv, dh), jnp.bfloat16)
         table = _np.full((b, npg), -1, _np.int32)
-        table[0, :3] = [1, 4, 2]
-        table[1, :2] = [0, 3]
-        lengths = _np.array([19, 10], _np.int32)
+        table[0, :2] = [1, 4]
+        table[1] = _np.arange(2, 2 + npg)
+        lengths = _np.array([19, npg * page], _np.int32)
         return capture_launch(
             _paged_decode_pallas, q, kp, kp, jnp.asarray(table),
             jnp.asarray(lengths), window=None, softcap=None, scale=1.0,
             interpret=True, name="paged_decode_attention")
-    # the online-softmax finalize fires on the last page of each row
+    # the online-softmax finalize fires on the last page block of each row
     return KernelCase("paged_decode_attention", build, epilogue_axis=1)
 
 

@@ -22,11 +22,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import metrics as obs_metrics
 from .names import pallas_names
+from .vmem import VMEM_BUDGET
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -153,110 +155,221 @@ def flash_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, window: Optional[int],
-                         softcap: Optional[float], page_size: int,
-                         n_pages: int, n_kv_heads: int, quant: bool = False):
-    """One grid step = one page of one sequence, all KV heads at once: the
-    page's ``(page, Hkv, Dh)`` block keeps the pool's full trailing dims
-    (the TPU's block rule refuses a single-head slice of them), and each
-    head's online-softmax state lives in its own row of the scratch.
+# A grid step costs a fixed few tenths of a microsecond, against about
+# 0.04 us to stream one 16-token page of K and V, so a step reads a block of
+# pages (``decode_pages_per_step``), and the blocks a row walks are bound by
+# its live range: a block wholly past the row's end (or before its window)
+# maps to the nearest live block, so the pipeline issues no copy for it, and
+# its compute is skipped.
+MAX_BLOCK_TOKENS = 512
 
-    ``quant`` selects int8 KV pages: two extra per-token scale refs
-    (``(1, page_size)`` rows of the scale buffers, selected by the same
-    page-table index map) dequantize in register — the key scale multiplies
-    the logits and the value scale the probabilities, so both stay in the
-    lane orientation the logits already have. Pages stream from HBM at
-    1 byte/element."""
-    if quant:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        (o_ref, acc_ref, m_ref, l_ref), ks_ref, vs_ref = rest, None, None
+
+def decode_pages_per_step(n_pages: int, page_size: int, n_kv_heads: int,
+                          groups: int, head_dim: int, *, q_dtype, kv_dtype,
+                          quant: bool) -> int:
+    """Pages of K and V one paged-decode grid step reads.
+
+    The most pages whose double-buffered K and V blocks (and int8 scale
+    rows), beside the query and output blocks and the softmax scratch,
+    fit SL104's VMEM budget, capped at ``MAX_BLOCK_TOKENS`` keys a step;
+    the largest divisor of ``n_pages`` under that cap where it is at least
+    half the cap, else the cap (the tail block's slots clamp into the
+    table)."""
+    kv_bytes = page_size * n_kv_heads * head_dim * np.dtype(kv_dtype).itemsize
+    per_page = 2 * 2 * (kv_bytes + (page_size * 4 if quant else 0))
+    fixed = (2 * 2 * n_kv_heads * groups * head_dim
+             * np.dtype(q_dtype).itemsize
+             + n_kv_heads * groups * (head_dim + 2 * _LANES) * 4)
+    cap = max(1, min(n_pages, MAX_BLOCK_TOKENS // page_size,
+                     (VMEM_BUDGET - fixed) // per_page))
+    div = max(p for p in range(1, cap + 1) if n_pages % p == 0)
+    return div if 2 * div >= cap else cap
+
+
+def _live_pages(length, *, page_size: int, n_pages: int,
+                window: Optional[int]):
+    """First and last logical page that hold a key the row attends to
+    (page 0 for an empty row)."""
+    last = jnp.minimum(jnp.maximum(length - 1, 0) // page_size, n_pages - 1)
+    if window is None:
+        return 0, last
+    first = jnp.maximum(length - window, 0) // page_size
+    return jnp.minimum(first, last), last
+
+
+def _live_block(blk, length, *, ppb: int, page_size: int, n_pages: int,
+                window: Optional[int]):
+    """Page block ``blk`` clamped to the row's live blocks: a dead block
+    becomes the nearest live one."""
+    first, last = _live_pages(length, page_size=page_size, n_pages=n_pages,
+                              window=window)
+    return jnp.minimum(jnp.maximum(blk, first // ppb), last // ppb)
+
+
+def _block_page(blk, j, length, *, ppb: int, page_size: int, n_pages: int,
+                window: Optional[int]):
+    """Logical page that slot ``j`` of page block ``blk`` reads: slot ``j``
+    of the nearest live block, clamped to the row's live pages (which
+    also keeps a tail block's slots inside the table)."""
+    first, last = _live_pages(length, page_size=page_size, n_pages=n_pages,
+                              window=window)
+    live = _live_block(blk, length, ppb=ppb, page_size=page_size,
+                       n_pages=n_pages, window=window)
+    return jnp.minimum(jnp.maximum(live * ppb + j, first), last)
+
+
+def _paged_decode_kernel(sp_ref, len_ref, q_ref, *refs, scale: float,
+                         window: Optional[int], softcap: Optional[float],
+                         page_size: int, ppb: int, n_pages: int,
+                         n_blocks: int, n_kv_heads: int,
+                         quant: bool = False):
+    """One grid step = one block of ``ppb`` pages of one sequence, all KV
+    heads at once. Each page's ``(page, Hkv, Dh)`` block keeps the pool's
+    full trailing dims (the TPU's block rule refuses a single-head slice of
+    them); each head concatenates its slice of the block's pages into one
+    ``(ppb * page, Dh)`` operand of one logit and one PV matmul, and keeps
+    its online-softmax state in its own row of the scratch. A block
+    outside the row's live range computes nothing.
+
+    refs: ``ppb`` K pages, ``ppb`` V pages, [``ppb`` K and ``ppb`` V scale
+    rows], the output, the scratch. ``quant`` selects int8 KV pages: the
+    per-token scale rows (``(1, page_size)`` rows of the scale buffers,
+    selected by the same page-table index map) dequantize in register —
+    the key scale multiplies the logits and the value scale the
+    probabilities, so both stay in the lane orientation the logits
+    already have. Pages stream from HBM at 1 byte/element."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    ks_refs, vs_refs = refs[2 * ppb:3 * ppb], refs[3 * ppb:4 * ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[(4 if quant else 2) * ppb:]
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    blk = pl.program_id(1)
 
-    @pl.when(p == 0)
+    @pl.when(blk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
     length = len_ref[b]                            # valid keys: kpos < length
-    groups = q_ref.shape[2]
-    kpos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (groups, page_size), 1)
-    mask = kpos < length
-    if window is not None:
-        mask &= kpos > (length - 1) - window
+    live = _live_block(blk, length, ppb=ppb, page_size=page_size,
+                       n_pages=n_pages, window=window) == blk
 
-    for h in range(n_kv_heads):
-        q = q_ref[0, h].astype(jnp.float32) * scale    # (G, Dh)
-        k = k_ref[0, :, h, :].astype(jnp.float32)      # (page, Dh)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    @pl.when(live & (length > 0))
+    def _block():
+        groups = q_ref.shape[2]
+        tokens = ppb * page_size
+        kpos = blk * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (groups, tokens), 1)
+        # a tail block's slots past the table repeat its last page
+        mask = kpos < jnp.minimum(length, n_pages * page_size)
+        if window is not None:
+            mask &= kpos > (length - 1) - window
         if quant:
-            s = s * ks_ref[...]                        # (1, page)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        s = jnp.where(mask, s, _NEG_INF)
+            ks = jnp.concatenate([r[...] for r in ks_refs], axis=1)
+            vs = jnp.concatenate([r[...] for r in vs_refs], axis=1)
 
-        m_prev = m_ref[h][:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        pexp = jnp.exp(s - m_new)
-        pexp = jnp.where(m_new > _NEG_INF / 2, pexp, 0.0)
-        corr = jnp.where(m_prev > _NEG_INF / 2, jnp.exp(m_prev - m_new),
-                         0.0)
-        l_new = corr * l_ref[h][:, :1] + jnp.sum(pexp, axis=1,
-                                                 keepdims=True)
-        if quant:
-            pexp = pexp * vs_ref[...]                  # (1, page)
-        v = v_ref[0, :, h, :].astype(jnp.float32)      # (page, Dh)
-        pv = jax.lax.dot_general(pexp, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[h] = acc_ref[h] * corr + pv
-        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        for h in range(n_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32) * scale    # (G, Dh)
+            k = jnp.concatenate(                           # (tokens, Dh)
+                [r[0, :, h, :].astype(jnp.float32) for r in k_refs], axis=0)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if quant:
+                s = s * ks                                 # (1, tokens)
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(mask, s, _NEG_INF)
 
-    @pl.when(p == n_pages - 1)
+            m_prev = m_ref[h][:, :1]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            pexp = jnp.exp(s - m_new)
+            pexp = jnp.where(m_new > _NEG_INF / 2, pexp, 0.0)
+            corr = jnp.where(m_prev > _NEG_INF / 2, jnp.exp(m_prev - m_new),
+                             0.0)
+            l_new = corr * l_ref[h][:, :1] + jnp.sum(pexp, axis=1,
+                                                     keepdims=True)
+            if quant:
+                pexp = pexp * vs                           # (1, tokens)
+            v = jnp.concatenate(
+                [r[0, :, h, :].astype(jnp.float32) for r in v_refs], axis=0)
+            pv = jax.lax.dot_general(pexp, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(blk == n_blocks - 1)
     def _finalize():
         l = l_ref[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
+def _count_decode(form: str, steps: int, ppb: int) -> None:
+    """Trace-time counters of the decode's blocking (like the junction
+    forward's): grid steps of each call, and pages a step reads, by form."""
+    reg = obs_metrics.get_registry()
+    reg.counter(
+        "repro_paged_decode_grid_steps_total",
+        "paged decode attention grid steps per traced call, by form",
+    ).inc(steps, form=form)
+    reg.gauge(
+        "repro_paged_decode_pages_per_step",
+        "KV pages a paged decode attention grid step reads, by form",
+    ).set(ppb, form=form)
+
+
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
                          window, softcap, scale, interpret,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, pages_per_step=None):
+    """Grid ``(B, ceil(n_pages / ppb))``; ``ppb`` comes from the shapes
+    (``decode_pages_per_step``) unless given (``pages_per_step``, a seam
+    for tests)."""
     b, hkv, g, dh = q.shape
     pool, page_size = k_pages.shape[:2]
     n_pages = page_table.shape[1]
     quant = k_scale is not None
+    ppb = pages_per_step or decode_pages_per_step(
+        n_pages, page_size, hkv, g, dh, q_dtype=q.dtype,
+        kv_dtype=k_pages.dtype, quant=quant)
+    n_blocks = -(-n_pages // ppb)
+    geo = dict(ppb=ppb, page_size=page_size, n_pages=n_pages, window=window)
 
-    # the physical page id comes from the scalar-prefetched table — this
-    # is the kernel-side form of the free-list indirection
-    def page(bb, p, pt, ln):
-        return jnp.maximum(pt[bb, p], 0)
+    # the physical page each slot of each block reads, clamped to the row's
+    # live range, is worked out for the whole table at once and
+    # scalar-prefetched: the kernel-side form of the free-list indirection,
+    # one load an index map
+    logical = _block_page(jnp.arange(n_blocks)[None, :, None],
+                          jnp.arange(ppb)[None, None, :],
+                          lengths[:, None, None], **geo)
+    slot_pages = jnp.maximum(jnp.take_along_axis(
+        page_table, logical.reshape(b, n_blocks * ppb), axis=1), 0)
 
-    kv_spec = pl.BlockSpec(
+    def page(bb, blk, sp, j):
+        return sp[bb, blk * ppb + j]
+
+    kv_specs = [pl.BlockSpec(
         (1, page_size, hkv, dh),
-        lambda bb, p, pt, ln: (page(bb, p, pt, ln), 0, 0, 0))
+        lambda bb, blk, sp, ln, j=j: (page(bb, blk, sp, j), 0, 0, 0))
+        for j in range(ppb)]
     q_spec = pl.BlockSpec((1, hkv, g, dh),
-                          lambda bb, p, pt, ln: (bb, 0, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [page_table, lengths, q, k_pages, v_pages]
+                          lambda bb, blk, sp, ln: (bb, 0, 0, 0))
+    in_specs = [q_spec] + kv_specs * 2
+    operands = [slot_pages, lengths, q] + [k_pages] * ppb + [v_pages] * ppb
     if quant:
         # per-token scale row of the (P+1, page) buffers, same page id;
         # viewed as (P+1, 1, page) so the one-row tile spans full dims
-        sc_spec = pl.BlockSpec(
+        sc_specs = [pl.BlockSpec(
             (pl.squeezed, 1, page_size),
-            lambda bb, p, pt, ln: (page(bb, p, pt, ln), 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.reshape(pool, 1, page_size),
-                     v_scale.reshape(pool, 1, page_size)]
+            lambda bb, blk, sp, ln, j=j: (page(bb, blk, sp, j), 0, 0))
+            for j in range(ppb)]
+        in_specs += sc_specs * 2
+        operands += ([k_scale.reshape(pool, 1, page_size)] * ppb
+                     + [v_scale.reshape(pool, 1, page_size)] * ppb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_pages),
+        grid=(b, n_blocks),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
@@ -265,9 +378,13 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
             pltpu.VMEM((hkv, g, _LANES), jnp.float32),
         ],
     )
+    form = "int8" if quant else jnp.dtype(k_pages.dtype).name.replace(
+        "bfloat16", "bf16")
+    _count_decode(form, b * n_blocks, ppb)
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window, softcap=softcap,
-        page_size=page_size, n_pages=n_pages, n_kv_heads=hkv, quant=quant)
+        page_size=page_size, ppb=ppb, n_pages=n_pages, n_blocks=n_blocks,
+        n_kv_heads=hkv, quant=quant)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -331,8 +448,10 @@ def paged_decode_attention(
     """Single-token attention over a paged KV cache; returns like ``q``.
 
     ``backend="auto"`` follows the repo convention: the Pallas kernel on
-    TPU (page table scalar-prefetched, one page per grid step, online
-    softmax across pages), the gather-based XLA lowering elsewhere.
+    TPU (page table scalar-prefetched, a block of pages per grid step,
+    online softmax across blocks, the blocks outside each row's live range
+    neither fetched nor computed), the gather-based XLA lowering
+    elsewhere.
     Unmapped table entries are safe: their logical positions are >= the
     sequence length, so they are masked before the softmax.
 

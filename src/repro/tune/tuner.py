@@ -220,8 +220,9 @@ def bench_decode(spec: dict, *, cache: Optional[_cache.TuneCache] = None,
                  save: bool = True) -> dict:
     """Measure decode-attention backends for one paged-KV regime.
 
-    The Pallas decode kernel has no tunable grid knobs (one page per grid
-    step is structural), so the candidate axis is backend only; the
+    The Pallas decode kernel has no tunable grid knobs (its pages per grid
+    step come from the shapes, ``decode_pages_per_step``), so the
+    candidate axis is backend only; the
     shipped kernel itself is certified by sparselint's CI gate.
     """
     import jax

@@ -376,6 +376,33 @@ def test_fwd_tiling_counters_count_at_trace_time():
     assert slots.value(form="batched", fan_in=4) == 2
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_blocking_counters_count_at_trace_time(quant):
+    """The paged decode's grid steps per call and pages per step, counted
+    when a call is traced: 3 rows over a table of 40 pages of 16 tokens
+    take the derived 20 pages a step, a grid of 3 x 2."""
+    from repro.kernels.flash_attention import paged_decode_attention
+    reg = metrics.get_registry()
+    steps = reg.counter("repro_paged_decode_grid_steps_total")
+    pages = reg.gauge("repro_paged_decode_pages_per_step")
+    form = "int8" if quant else "bf16"
+    kv_dtype = jnp.int8 if quant else jnp.bfloat16
+    q = jnp.zeros((3, 2, 2, 128), jnp.bfloat16)
+    kv = jnp.zeros((41, 16, 2, 128), kv_dtype)
+    sc = jnp.ones((41, 16), jnp.float32) if quant else None
+    table = jnp.full((3, 40), -1, jnp.int32).at[:, 0].set(
+        jnp.arange(3, dtype=jnp.int32))
+    before = steps.value(form=form)
+    f = jax.jit(lambda q, kv, t, ln: paged_decode_attention(
+        q, kv, kv, t, ln, backend="pallas", interpret=True, k_scale=sc,
+        v_scale=sc))
+    ln = jnp.asarray([1, 5, 16], jnp.int32)
+    f(q, kv, table, ln)
+    f(q, kv, table, ln)  # cached executable: no new count
+    assert steps.value(form=form) == before + 3 * 2
+    assert pages.value(form=form) == 20
+
+
 # ---------------------------------------------------------------------------
 # surfaces: HTTP endpoint, timed_call
 # ---------------------------------------------------------------------------

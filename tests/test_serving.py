@@ -538,36 +538,121 @@ def test_engine_reclaim_window_disabled_for_global_layers():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("window,softcap", [(None, None), (6, None),
-                                            (None, 30.0), (6, 30.0)])
-def test_paged_decode_kernel_interpret_matches_xla(window, softcap):
+# (window, softcap, lengths, n_pages, pages a step (None: derived), int8
+# KV). The derived blocking takes all 5 pages in one step; 2 pages a step
+# over 5 pages leaves a 1-page tail block, over 6 none. Lengths of 0, one
+# page (4), one block (8) and the full table (20); a window of 6 over a
+# row of 17 starts in page 2, past the first block.
+PAGED_DECODE_CASES = [
+    pytest.param(None, None, (3, 11, 17), 5, None, False, id="None-None"),
+    pytest.param(6, None, (3, 11, 17), 5, None, False, id="6-None"),
+    pytest.param(None, 30.0, (3, 11, 17), 5, None, False, id="None-30.0"),
+    pytest.param(6, 30.0, (3, 11, 17), 5, None, False, id="6-30.0"),
+    pytest.param(None, None, (3, 11, 17), 6, 2, False, id="blocks"),
+    pytest.param(None, None, (0, 4, 8, 20), 5, 2, False, id="tail-edges"),
+    pytest.param(6, None, (17, 20, 5, 0), 5, 2, False, id="window-late"),
+    pytest.param(None, 30.0, (0, 4, 8, 20), 5, 2, False, id="softcap-tail"),
+    pytest.param(6, None, (0, 4, 8, 20), 5, 2, True, id="int8-tail"),
+    pytest.param(None, 30.0, (3, 11, 17), 5, None, True, id="int8"),
+]
+
+
+@pytest.mark.parametrize("window,softcap,lengths,n_pages,ppb,quant",
+                         PAGED_DECODE_CASES)
+def test_paged_decode_kernel_interpret_matches_xla(window, softcap, lengths,
+                                                   n_pages, ppb, quant):
+    from repro.kernels.flash_attention import _paged_decode_pallas
     rng = np.random.default_rng(0)
-    b, hkv, g, dh, page, n_pages, total = 3, 2, 3, 16, 4, 5, 12
+    b, hkv, g, dh, page, total = len(lengths), 2, 3, 16, 4, 24
     q = jnp.asarray(rng.normal(size=(b, hkv, g, dh)), jnp.float32)
     k_pages = jnp.asarray(rng.normal(size=(total, page, hkv, dh)),
                           jnp.float32)
     v_pages = jnp.asarray(rng.normal(size=(total, page, hkv, dh)),
                           jnp.float32)
+    k_scale = v_scale = None
+    if quant:
+        k_pages = jnp.asarray(rng.integers(-127, 128, k_pages.shape),
+                              jnp.int8)
+        v_pages = jnp.asarray(rng.integers(-127, 128, v_pages.shape),
+                              jnp.int8)
+        k_scale = jnp.asarray(rng.uniform(0.005, 0.02, (total, page)),
+                              jnp.float32)
+        v_scale = jnp.asarray(rng.uniform(0.005, 0.02, (total, page)),
+                              jnp.float32)
     # rows with different lengths; unmapped tail entries are -1
     table = np.full((b, n_pages), -1, np.int32)
     perm = rng.permutation(total - 1)  # page `total-1` plays trash
-    lengths = np.asarray([3, 11, 17], np.int32)
-    lengths = np.minimum(lengths, n_pages * page)
+    lengths = np.minimum(np.asarray(lengths, np.int32), n_pages * page)
     k = 0
     for i in range(b):
         for pg in range(-(-int(lengths[i]) // page)):
             table[i, pg] = perm[k]
             k += 1
-    ref = paged_decode_attention(q, k_pages, v_pages,
-                                 jnp.asarray(table), jnp.asarray(lengths),
-                                 window=window, softcap=softcap,
-                                 backend="xla")
-    out = paged_decode_attention(q, k_pages, v_pages,
-                                 jnp.asarray(table), jnp.asarray(lengths),
-                                 window=window, softcap=softcap,
-                                 backend="pallas", interpret=True)
+    args = (q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lengths))
+    kw = dict(window=window, softcap=softcap, k_scale=k_scale,
+              v_scale=v_scale)
+    ref = paged_decode_attention(*args, backend="xla", **kw)
+    if ppb is None:
+        out = paged_decode_attention(*args, backend="pallas",
+                                     interpret=True, **kw)
+    else:
+        out = _paged_decode_pallas(*args, scale=dh ** -0.5, interpret=True,
+                                   pages_per_step=ppb, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    assert not np.asarray(out)[lengths == 0].any()
+
+
+@pytest.mark.parametrize("length,window", [
+    (0, None), (1, None), (4, None), (5, None), (32, None), (33, None),
+    (100, None), (148, None), (10_000, None), (100, 10), (100, 60),
+    (148, 30), (20, 100)])
+def test_paged_decode_dead_blocks_repeat_nearest_live_block(length, window):
+    """The page blocks a row walks: a block past the row's last live page,
+    or before the page its window starts in, maps to the nearest live
+    block, slot by slot, so the pipeline fetches nothing for it; a live
+    block maps to itself. Every slot's page lies in the table and in the
+    row's live pages (37 pages of 4 tokens, 8 a block: a 5-page tail)."""
+    from repro.kernels.flash_attention import _block_page, _live_block
+    page, n_pages, ppb = 4, 37, 8
+    n_blocks = -(-n_pages // ppb)
+    geo = dict(ppb=ppb, page_size=page, n_pages=n_pages, window=window)
+    keys = [kp for kp in range(min(length, n_pages * page))
+            if window is None or kp > length - 1 - window]
+    live_pages = {kp // page for kp in keys} or {0}
+    live_blocks = sorted({p // ppb for p in live_pages})
+    pages = {}
+    for blk in range(n_blocks):
+        want = min(max(blk, live_blocks[0]), live_blocks[-1])
+        assert int(_live_block(blk, length, **geo)) == want
+        pages[blk] = [int(_block_page(blk, j, length, **geo))
+                      for j in range(ppb)]
+        assert all(min(live_pages) <= p <= max(live_pages)
+                   for p in pages[blk])
+        if blk in live_blocks:
+            # every live page of the block is read in its own slot
+            assert {p for p in live_pages if p // ppb == blk} \
+                <= set(pages[blk])
+    for blk in range(n_blocks):
+        assert pages[blk] == pages[min(max(blk, live_blocks[0]),
+                                       live_blocks[-1])]
+
+
+@pytest.mark.parametrize("n_pages,page,quant,want", [
+    (256, 16, False, 32), (256, 16, True, 32), (40, 16, False, 20),
+    (37, 16, False, 32), (5, 4, False, 5), (12, 512, False, 1)])
+def test_decode_pages_per_step(n_pages, page, quant, want):
+    """qwen2_7b's decode (4 KV heads of 128, 7 groups): at most 512 keys a
+    step, a divisor of the table where one is at least half that, and
+    what fits the VMEM budget."""
+    from repro.kernels.flash_attention import decode_pages_per_step
+    from repro.kernels.vmem import VMEM_BUDGET
+    ppb = decode_pages_per_step(n_pages, page, 4, 7, 128,
+                                q_dtype=jnp.bfloat16,
+                                kv_dtype=jnp.int8 if quant else jnp.bfloat16,
+                                quant=quant)
+    assert ppb == want
+    assert 2 * 2 * ppb * page * 4 * 128 * 2 <= VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------

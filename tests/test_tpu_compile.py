@@ -17,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -225,24 +226,60 @@ def test_folded_fwd_compiles_at_cell_widths(one_chip, junction, experts,
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_decode_compiles(one_chip, quant):
-    slots, page_size, pages_per_seq, pool = 4, 16, 40, 161
+    """The paged decode at the chat cell's shapes (8 slots of 256 pages of
+    16 tokens, qwen2_7b's 4 KV heads of 128 and 7 groups, a pool of 1,500
+    pages): it compiles as one kernel under its kernel name, and the launch
+    sparselint captures there has no finding: its blocks of 32 pages a step
+    fit SL104's budget, each row's output is revisited in consecutive steps
+    (SL101) and finalized on its last block (SL103), and every page index,
+    clamped ones included, stays in the pool (SL105). The rows' lengths
+    mix an empty row, one page, one block and the full table."""
+    from repro.analysis import grid_pass
+    from repro.analysis.capture import capture_launch
+    from repro.kernels.flash_attention import _paged_decode_pallas
+    slots, page_size, pages_per_seq, pool = 8, 16, 256, 1500
     hkv, dh = QWEN.n_kv_heads, QWEN.head_dim
     groups = QWEN.n_heads // hkv
-    q = _spec((slots, hkv, groups, dh), BF16, one_chip)
+    assert (hkv, groups, dh) == (4, 7, 128)
     kv_dtype = jnp.int8 if quant else BF16
-    kv = _spec((pool, page_size, hkv, dh), kv_dtype, one_chip)
-    pt = _spec((slots, pages_per_seq), jnp.int32, one_chip)
-    ln = _spec((slots,), jnp.int32, one_chip)
+    shapes = [(slots, hkv, groups, dh), (pool, page_size, hkv, dh),
+              (pool, page_size, hkv, dh), (slots, pages_per_seq), (slots,)]
+    dtypes = [BF16, kv_dtype, kv_dtype, jnp.int32, jnp.int32]
     if quant:
-        sc = _spec((pool, page_size), jnp.float32, one_chip)
-        kernels = _compile(
-            lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
-                q, k, v, pt, ln, backend="pallas", k_scale=ks, v_scale=vs),
-            q, kv, kv, pt, ln, sc, sc)
-    else:
-        kernels = _compile(lambda q, k, v, pt, ln: paged_decode_attention(
-            q, k, v, pt, ln, backend="pallas"), q, kv, kv, pt, ln)
-    assert set(kernels) == {"paged_decode_attention"}
+        shapes += [(pool, page_size)] * 2
+        dtypes += [jnp.float32] * 2
+
+    def decode(q, k, v, pt, ln, *sc):
+        return paged_decode_attention(
+            q, k, v, pt, ln, backend="pallas", k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None)
+
+    kernels = _compile(decode, *[_spec(sh, dt, one_chip)
+                                 for sh, dt in zip(shapes, dtypes)])
+    assert kernels == {"paged_decode_attention": 1}
+
+    lengths = np.asarray([0, 1, 16, 17, 512, 513, 2000, 4096], np.int32)
+    table = np.full((slots, pages_per_seq), -1, np.int32)
+    perm = np.random.default_rng(0).permutation(pool)
+    used = 0
+    for i, ln in enumerate(lengths):
+        n = -(-int(ln) // page_size)
+        table[i, :n] = perm[used:used + n]
+        used += n
+    args = [jnp.zeros(sh, dt) for sh, dt in zip(shapes, dtypes)]
+    args[3], args[4] = jnp.asarray(table), jnp.asarray(lengths)
+    launch = capture_launch(
+        _paged_decode_pallas, *args[:5], window=None, softcap=None,
+        scale=dh ** -0.5, interpret=False,
+        k_scale=args[5] if quant else None,
+        v_scale=args[6] if quant else None)
+    case = grid_pass.KernelCase("paged_decode_attention", lambda: launch,
+                                epilogue_axis=1)
+    findings, cost = grid_pass.analyze_launch(launch, case)
+    assert findings == [], [f.message for f in findings]
+    assert cost["vmem_bytes_per_step"] <= grid_pass.DEFAULT_VMEM_BUDGET
+    # blocked: 32 pages a step, 8 blocks a row
+    assert launch.grid == (slots, pages_per_seq // 32)
 
 
 @pytest.mark.parametrize("chunk", [1, 64], ids=["decode", "prefill"])
